@@ -61,6 +61,7 @@ from repro.core import simulator as sim
 from repro.core.adapter_scheduler import (EagerPolicy, EpochSchedulerPolicy,
                                           simulate_adapter_serving)
 from repro.core.simulator import GPU_PAPER, TPU_V5E
+from repro.launch.compile_cache import enable_compile_cache
 
 ROWS = []
 
@@ -1705,6 +1706,7 @@ def main(argv=None) -> None:
                     help="print the bench registry (name, --small "
                          "support, one-line description) and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.list:
         for b in BENCHES:
             doc = (inspect.getdoc(b) or "").split("\n")[0]

@@ -1,4 +1,4 @@
-"""qwen3-1.7b — qk_norm, GQA [hf:Qwen/Qwen3-8B; hf]."""
+"""qwen3-1.7b — qk_norm, GQA [hf:Qwen/Qwen3-1.7B; hf]."""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -14,5 +14,5 @@ CONFIG = ArchConfig(
     qk_norm=True,
     rope_theta=1e6,
     tie_embeddings=True,
-    source="[hf:Qwen/Qwen3-8B; hf]",
+    source="[hf:Qwen/Qwen3-1.7B; hf]",
 )

@@ -2,11 +2,13 @@
 pipeline-parallel loading, inference during loading, strategy switching,
 crash injection and recovery (paper §4.1–§4.4).
 
-This engine executes REAL models (repro.models) over *logical devices* — on
-this CPU container the devices are bookkeeping entities (what is loaded
-where, whose KV lives where) while compute runs on the host; on a real TPU
-slice the same state machine drives per-device `jax.device_put` of segment
-shards and the shard_map pipeline in repro/distributed/pipeline.py.  Timing
+This engine executes REAL models (repro.models) over *logical devices*.
+The devices are bookkeeping entities, on the CPU and on a TPU alike: a
+load round records which segments a device holds and whose KV lives
+where, and moves no bytes — the parameters are handed in whole and
+already on the device, so ``time_to_ready`` is not a load time.  With
+several XLA devices the TTFT-critical prefill runs the shard_map belt of
+repro/distributed/pipeline.py, stage-sharded over a real mesh.  Timing
 comes from core/simulator.py; this module owns *correctness*:
 
   * a request admitted before full load produces EXACTLY the same tokens as
@@ -31,6 +33,7 @@ from repro.configs.base import ArchConfig
 from repro.core import analytic
 from repro.core.kv_reconstruct import reconstruct_cache
 from repro.core.planner import (LoadPlan, make_plan, reassign, viable_chain)
+from repro.launch.mesh import auto_mesh
 from repro.lora.adapters import LoRAAdapter, merge_lora, unmerge_lora
 from repro.models import transformer
 
@@ -236,8 +239,8 @@ class PipeBoostEngine:
                    budget: Optional[int] = None) -> threading.Thread:
         """Start the asynchronous background fill: a daemon thread runs
         ``load_round`` until fully loaded (or ``stop_fill``).  Loading is
-        pure host-side bookkeeping + ``device_put`` scheduling, so it
-        overlaps with jitted serving steps on the main thread."""
+        pure host-side bookkeeping, so it overlaps with jitted serving
+        steps on the main thread."""
         if self._fill_thread is not None and self._fill_thread.is_alive():
             return self._fill_thread
         self._fill_interval_s = interval_s
@@ -424,8 +427,7 @@ class PipeBoostEngine:
                     break
             if not n_stages:
                 return False
-            mesh = jax.make_mesh((n_xla // n_stages, n_stages),
-                                 ("data", "stage"))
+            mesh = auto_mesh((n_xla // n_stages, n_stages), ("data", "stage"))
         else:
             n_stages = mesh.shape["stage"]
             if self.cfg.n_layers % n_stages:

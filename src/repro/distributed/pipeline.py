@@ -27,7 +27,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.models.attention import default_block_q
@@ -238,11 +237,11 @@ def build_pipeline_prefill(cfg: ArchConfig, *, n_stages: int, n_micro: int,
         else:
             out_specs = P("data", None)
         with default_block_q(512):
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(pspecs, bspecs),
                 out_specs=out_specs,
-                check_rep=False,
+                check_vma=False,
             )(params, batch)
 
     return f
@@ -362,9 +361,9 @@ def build_pipeline_prefill_seqchunk(cfg: ArchConfig, *, n_stages: int,
         pspecs = jax.tree_util.tree_map_with_path(pspec_params, params)
         bspecs = jax.tree.map(
             lambda a: P("data", *([None] * (a.ndim - 1))), batch)
-        return shard_map(body, mesh=mesh, in_specs=(pspecs, bspecs),
-                         out_specs=P("data", None), check_rep=False,
-                         )(params, batch)
+        return jax.shard_map(body, mesh=mesh, in_specs=(pspecs, bspecs),
+                             out_specs=P("data", None), check_vma=False,
+                             )(params, batch)
 
     return f
 

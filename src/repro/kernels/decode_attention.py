@@ -21,6 +21,12 @@ path no longer has to fall back to the XLA lowering.
 
 Layouts: q (B, Hq, d); k/v (B, Hkv, C, d); lens (B,) int32;
 k/v_new (B, Hkv, 1, d); slot_mask (B, C) bool/int -> out (B, Hq, d).
+
+Mosaic tiling: the last two dims of every block must be multiples of
+(8, 128) or equal the array's own.  So the kernel sees the query, the
+output and the slot mask with a unit axis in front of their last dim
+((B, Hq, 1, d) and (B, 1, C)), and ``block_k`` is either a multiple of
+128 or the whole (padded) cache length.
 """
 from __future__ import annotations
 
@@ -65,7 +71,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, scale: float,
     if masked:
         # per-slot validity (ring buffers): ANDed with the prefix-length
         # mask, exactly like the XLA lowering's kv_slot_mask
-        mask = mask & (smask_ref[...] != 0)
+        mask = mask & (smask_ref[0] != 0)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -94,7 +100,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, scale: float,
             l = l * c + p_new
             acc = acc * c + p_new * vn
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :] = (acc / l)[0].astype(o_ref.dtype)
+        o_ref[0, 0] = (acc / l).astype(o_ref.dtype)
 
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -140,7 +146,8 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         if pad:
             sm = jnp.pad(sm, ((0, 0), (0, pad)))
         in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b, h, ki, lens: (b, ki)))
+            pl.BlockSpec((1, 1, block_k), lambda b, h, ki, lens: (b, 0, ki)))
+        sm = sm[:, None, :]                               # (B, 1, C)
         inputs.append(sm)
     if merge_new:
         in_specs += [
@@ -152,7 +159,8 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=(B, Hq, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, h, ki, lens: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, d),
+                               lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -162,7 +170,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, d), q.dtype),
         interpret=interpret,
     )(lens.astype(jnp.int32), *inputs)
-    return out
+    return out[:, :, 0, :]

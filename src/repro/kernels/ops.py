@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container kernels execute in interpret mode (Python semantics,
-exact math); on TPU the same calls compile to Mosaic.  ``interpret`` is
-resolved from the backend unless forced.  Layout adapters translate from
-the model zoo's (B, S, H, d) convention to the kernels' (B, H, S, d).
+On the CPU kernels execute in interpret mode (Python semantics, exact
+math); on TPU ``decode_attention`` and ``lora_merge`` compile to Mosaic,
+while ``ssd_scan`` and ``rglru_scan`` do not (see their modules).
+``interpret`` is resolved from the backend unless forced.  Layout
+adapters translate from the model zoo's (B, S, H, d) convention to the
+kernels' (B, H, S, d).
 
 See ``docs/ARCHITECTURE.md`` § "Models and kernels".
 """

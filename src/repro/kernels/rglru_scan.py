@@ -12,6 +12,9 @@ computed as a (Tb, Tb) masked matrix applied on the VPU — numerically safe
 because cumA_t - cumA_k <= 0 within the mask (a_t in (0, 1]).
 
 Layouts: log_a/bx (B, S, W) f32; h0 (B, W) f32 -> (y (B, S, W), h_T (B, W)).
+
+Interpret mode only: Mosaic refuses this kernel on a TPU v5e (a block
+shape is not aligned to the (8, 128) tiling), and no model calls it.
 """
 from __future__ import annotations
 

@@ -9,6 +9,9 @@ VMEM scratch across the sequential chunk dimension (innermost grid axis).
 Layouts: x (B, H, nc, Q, P); dt (B, H, nc, Q, 1); A (1, H);
          Bm/Cm (B, nc, Q, N)  [single B/C group broadcast over heads]
 Outputs: y (B, H, nc, Q, P); final_state (B, H, P, N).
+
+Interpret mode only: Mosaic refuses this kernel on a TPU v5e (``cumsum``
+has no TPU lowering), and no model calls it.
 """
 from __future__ import annotations
 

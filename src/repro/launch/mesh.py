@@ -8,13 +8,25 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with ``Auto`` axes.  The repo places arrays with
+    ``NamedSharding`` and ``shard_map`` and leaves the rest to the
+    partitioner; ``make_mesh``'s default ``Explicit`` axes would demand
+    sharding-in-types everywhere (``jax.set_mesh``, ``out_sharding=``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The assignment's production mesh: 16x16 per pod, 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_pipeline_mesh(n_stages: int, *, total: Optional[int] = None):
@@ -25,7 +37,7 @@ def make_pipeline_mesh(n_stages: int, *, total: Optional[int] = None):
     """
     total = total or 256
     assert total % n_stages == 0, (total, n_stages)
-    return jax.make_mesh((total // n_stages, n_stages), ("data", "stage"))
+    return auto_mesh((total // n_stages, n_stages), ("data", "stage"))
 
 
 def make_host_mesh(axes: Tuple[str, ...] = ("data", "model")):
@@ -33,7 +45,7 @@ def make_host_mesh(axes: Tuple[str, ...] = ("data", "model")):
     n = len(jax.devices())
     shape = [1] * len(axes)
     shape[0] = n
-    return jax.make_mesh(tuple(shape), axes)
+    return auto_mesh(shape, axes)
 
 
 def pipeline_stages_for(n_layers: int, max_stages: int = 16) -> int:

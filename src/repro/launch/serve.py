@@ -15,8 +15,11 @@ Serverless cluster (router + autoscaler + cross-server crash re-routing):
 server replicas, optionally crashes one server after ``--crash-at``
 completions (its in-flight requests re-route to survivors and it rejoins
 via a fresh pipelined cold start), and prints TTFT/TBT percentiles, queue
-depth, and GPU-seconds.  CPU runs use reduced configs (functional path);
-the same engines drive device_put-sharded weights on a real slice.
+depth, and GPU-seconds.  On the CPU the model is cut to a few layers at
+toy width; on a TPU it runs at the configuration's full width.  Loading
+is bookkeeping: the engine's load rounds record which segments each
+logical device holds and move no bytes, because the parameters are made
+whole on the device before serving starts.
 
 See ``docs/ARCHITECTURE.md`` § "Launch".
 """
@@ -32,6 +35,7 @@ import numpy as np
 from repro.configs.base import ARCH_IDS, get_arch
 from repro.core.adapter_scheduler import EpochSchedulerPolicy
 from repro.core.engine import PipeBoostEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lora.adapters import init_lora, merge_lora, randomize_lora
 from repro.models import transformer as T
 from repro.serving.engine import ServeRequest, ServingEngine
@@ -91,9 +95,14 @@ def run_cluster(cfg, params, args):
         print(f"  metrics written to {args.metrics_json}")
     if int(s["n_completed"]) != len(trace):
         raise SystemExit("cluster run did not complete all requests")
+    return router
 
 
-def main(argv=None):
+def main(argv=None, cfg=None):
+    """Parse ``argv`` and serve.  ``cfg`` serves that configuration as
+    given, in place of ``--arch`` and the CPU depth cut.  Returns the
+    ``ServingEngine`` (single server) or the ``ClusterRouter``
+    (``--cluster``) so a caller can check what was served."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen3-1.7b")
     ap.add_argument("--devices", type=int, default=4)
@@ -126,26 +135,27 @@ def main(argv=None):
     ap.add_argument("--metrics-json", default="",
                     help="--cluster: also dump ClusterMetrics JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_arch(args.arch)
-    if jax.default_backend() == "cpu":
-        n_dev = args.devices if not args.cluster else min(args.devices, 2)
-        if args.cluster and n_dev != args.devices:
-            print(f"[cpu] clamping --devices {args.devices} -> {n_dev} "
-                  f"per server (reduced functional configs)")
-        period = max(1, len(cfg.block_pattern) or 1)
-        depth = ((2 * n_dev + period - 1) // period) * period
-        cfg = cfg.reduced(n_layers=depth)  # >= 1 segment per device
-        if args.cluster:
-            args.devices = n_dev
+    if cfg is None:
+        cfg = get_arch(args.arch)
+        if jax.default_backend() == "cpu":
+            n_dev = args.devices if not args.cluster else min(args.devices, 2)
+            if args.cluster and n_dev != args.devices:
+                print(f"[cpu] clamping --devices {args.devices} -> {n_dev} "
+                      f"per server (reduced functional configs)")
+            period = max(1, len(cfg.block_pattern) or 1)
+            depth = ((2 * n_dev + period - 1) // period) * period
+            cfg = cfg.reduced(n_layers=depth)  # >= 1 segment per device
+            if args.cluster:
+                args.devices = n_dev
     if not cfg.has_decode:
         raise SystemExit(f"{cfg.name} is encoder-only; no serve loop")
     key = jax.random.PRNGKey(0)
     params = T.init_params(cfg, key)
 
     if args.cluster:
-        run_cluster(cfg, params, args)
-        return
+        return run_cluster(cfg, params, args)
 
     # overlapped cold start through the PipeBoost engine: one loading
     # round flips `ready` (each device holds ~1/N of the model); the rest
@@ -213,6 +223,7 @@ def main(argv=None):
                 print(f"  recovered: {stats.get('reconstruct')}")
             tok = jnp.argmax(eng.decode(tok), -1).astype(jnp.int32)
         print("  decode continued through the crash")
+    return srv
 
 
 if __name__ == "__main__":

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import SHAPES, get_arch
-from repro.launch.mesh import data_axes, pipeline_stages_for
+from repro.launch.mesh import auto_mesh, data_axes, pipeline_stages_for
 
 
 def test_pipeline_stage_counts():
@@ -39,6 +39,7 @@ _SUBPROC = textwrap.dedent("""
     from repro.configs.base import get_arch
     from repro.distributed import shardings as shd
     from repro.distributed.context import ShardingPolicy, use_policy
+    from repro.launch.mesh import auto_mesh
     from repro.models import transformer as T
 
     cfg = get_arch("qwen3-1.7b").reduced(n_layers=4, vocab_size=256)
@@ -49,7 +50,7 @@ _SUBPROC = textwrap.dedent("""
     # single-device reference
     ref, _ = T.forward(cfg, params, batch, mode="train")
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     pol = ShardingPolicy(mesh, dp_axes=("data",), seq_axis="model")
     pspec = shd.param_specs(cfg, params, mesh, mode="fsdp")
     bspec = shd.batch_specs(cfg, batch, mesh, shard_seq=True)
@@ -69,7 +70,7 @@ _SUBPROC = textwrap.dedent("""
     # pipeline lowerings == plain forward (prefill logits)
     from repro.distributed.pipeline import (build_pipeline_prefill,
                                             build_pipeline_prefill_seqchunk)
-    pmesh = jax.make_mesh((2, 4), ("data", "stage"))
+    pmesh = auto_mesh((2, 4), ("data", "stage"))
     f = build_pipeline_prefill(cfg, n_stages=4, n_micro=2, mesh=pmesh,
                                seq_len=32)
     lg_pipe = f(params, batch)
@@ -102,7 +103,7 @@ def test_sharded_equals_single_device_and_pipeline():
 def test_param_specs_divisible():
     """Every sharded dim must divide by its axis product (all archs)."""
     from repro.distributed import shardings as shd
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         axis_names = ("data", "model")
@@ -138,6 +139,7 @@ _ELASTIC = textwrap.dedent("""
     from jax.sharding import Mesh
     from repro.configs.base import get_arch
     from repro.distributed import shardings as shd
+    from repro.launch.mesh import auto_mesh
     from repro.training.checkpoint import Checkpointer
     from repro.training.data import SyntheticLM
     from repro.training.optimizer import AdamWConfig
@@ -156,7 +158,7 @@ _ELASTIC = textwrap.dedent("""
 
     # train 2 steps on an 8-device mesh (FSDP-sharded state)
     devs = jax.devices()
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"), devices=devs[:8])
+    mesh8 = auto_mesh((4, 2), ("data", "model"), devices=devs[:8])
     state = init_train_state(cfg, jax.random.PRNGKey(0), jnp.float32)
     spec8 = shd.param_specs(cfg, state.params, mesh8, mode="fsdp")
     sspec8 = type(state)(spec8, type(state.opt)(
@@ -168,7 +170,7 @@ _ELASTIC = textwrap.dedent("""
         ck = Checkpointer(d)
         ck.save(2, state, extra={"data": ds.state()})
         # ELASTIC RESTART: half the devices died -> new 4-device mesh
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"), devices=devs[:4])
+        mesh4 = auto_mesh((2, 2), ("data", "model"), devices=devs[:4])
         tmpl = init_train_state(cfg, jax.random.PRNGKey(0), jnp.float32)
         spec4 = shd.param_specs(cfg, tmpl.params, mesh4, mode="fsdp")
         sspec4 = type(tmpl)(spec4, type(tmpl.opt)(
