@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ArchConfig
 from repro.core.adapter_scheduler import EpochSchedulerPolicy
 from repro.models import transformer
@@ -151,6 +152,7 @@ class ContinuousBatcher:
         self.prefix_cache = None
         self._prefix_evict_base = 0      # evictions before attach (delta)
         self.n_prefill_tokens = 0        # real (unpadded) tokens prefilled
+        self.n_prefill_padded_tokens = 0  # rows x bucket of each prefill call
         self.prefix_hits = 0             # admissions served from the cache
         self.prefix_hit_tokens = 0       # prompt tokens NOT re-prefilled
         self._sampler = sampler or (lambda lg: jnp.argmax(lg, axis=-1))
@@ -342,27 +344,33 @@ class ContinuousBatcher:
         carrying a generated prefix — take the normal prefill path.
         """
         assert len(reqs) <= len(self.free), (len(reqs), len(self.free))
-        hits: List[Tuple[ServeRequest, Any]] = []
-        misses: List[ServeRequest] = []
-        for r in reqs:
-            h = None
-            if (self.prefix_cache is not None and self._can_bucket
-                    and not r.generated):
-                h = self.prefix_cache.probe(self.cfg.name, r.adapter,
-                                            np.asarray(r.tokens, np.int64))
-            if h is None:
-                misses.append(r)
+        with tracing.span("pb.admit") as sp:
+            hits: List[Tuple[ServeRequest, Any]] = []
+            misses: List[ServeRequest] = []
+            for r in reqs:
+                h = None
+                if (self.prefix_cache is not None and self._can_bucket
+                        and not r.generated):
+                    h = self.prefix_cache.probe(
+                        self.cfg.name, r.adapter,
+                        np.asarray(r.tokens, np.int64))
+                if h is None:
+                    misses.append(r)
+                else:
+                    hits.append((r, h))
+            if sp:
+                sp.end(rids=[r.rid for r in reqs],
+                       prompt_lens=[len(r.tokens) for r in reqs],
+                       prefix_hits=len(hits))
+            if hits:
+                self._admit_prefix_hits(hits)
+            if not misses:
+                return
+            if not self._can_bucket:
+                for r in misses:
+                    self._admit_rows([r])
             else:
-                hits.append((r, h))
-        if hits:
-            self._admit_prefix_hits(hits)
-        if not misses:
-            return
-        if not self._can_bucket:
-            for r in misses:
-                self._admit_rows([r])
-        else:
-            self._admit_rows(misses)
+                self._admit_rows(misses)
 
     def _admit_prefix_hits(self, hits: List[Tuple[ServeRequest, Any]]
                            ) -> None:
@@ -470,25 +478,32 @@ class ContinuousBatcher:
             slots[i] = slot
             valid[i] = True
             assigned.append((i, slot, req))
+        self.n_prefill_padded_tokens += P * bucket
         backend = self._choose_prefill_backend(P, bucket)
-        if backend == "pipeline":
-            # TTFT-critical cold-start path: the prompt runs the shard_map
-            # pipeline belt over the partially-loaded stage chain; the slot
-            # write reuses the shared donated scatter
-            logits, state = self._pipe_prefill(
-                self.params, {"tokens": jnp.asarray(toks),
-                              "last_index": jnp.asarray(last_idx)})
-            self.cache = self._scatter_fused(
-                self.cache, state, jnp.asarray(slots),
-                jnp.asarray(last_idx + 1), jnp.asarray(valid))
-            first = self._sampler(logits).astype(jnp.int32)
-            self.n_prefill_pipeline += len(reqs)
-        else:
-            first, self.cache = self._prefill_fused(
-                self.params, jnp.asarray(toks), jnp.asarray(last_idx),
-                jnp.asarray(slots), jnp.asarray(valid), self.cache)
-        # pbcheck: disable=R2 (designed sync: admission reads first tokens to catch immediate EOS before slot commit)
-        first_host = np.asarray(first)
+        with tracing.span("pb.prefill") as sp:
+            if backend == "pipeline":
+                # TTFT-critical cold-start path: the prompt runs the
+                # shard_map pipeline belt over the partially-loaded stage
+                # chain; the slot write reuses the shared donated scatter
+                logits, state = self._pipe_prefill(
+                    self.params, {"tokens": jnp.asarray(toks),
+                                  "last_index": jnp.asarray(last_idx)})
+                self.cache = self._scatter_fused(
+                    self.cache, state, jnp.asarray(slots),
+                    jnp.asarray(last_idx + 1), jnp.asarray(valid))
+                first = self._sampler(logits).astype(jnp.int32)
+                self.n_prefill_pipeline += len(reqs)
+            else:
+                first, self.cache = self._prefill_fused(
+                    self.params, jnp.asarray(toks), jnp.asarray(last_idx),
+                    jnp.asarray(slots), jnp.asarray(valid), self.cache)
+            with tracing.span("pb.prefill.wait"):
+                # pbcheck: disable=R2 (designed sync: admission reads first tokens to catch immediate EOS before slot commit)
+                first_host = np.asarray(first)
+            if sp:
+                sp.end(backend=backend, rows=P, bucket=bucket,
+                       real_tokens=int(last_idx.sum()) + len(reqs),
+                       rids=[r.rid for r in reqs])
         self.n_prefill_calls += 1
         self.n_prefill_reqs += len(reqs)
         for i, slot, req in assigned:
@@ -511,40 +526,56 @@ class ContinuousBatcher:
         if not self.active:
             return []        # no sampler/decode work when nothing is active
         t0 = time.perf_counter()
-        if self._io_dirty:
-            toks = np.zeros((self.n_slots,), np.int32)
-            act = np.zeros((self.n_slots,), bool)
-            for slot, req in self.active.items():
-                toks[slot] = req.generated[-1]
-                act[slot] = True
-            self._dev_tokens = jnp.asarray(toks)
-            self._dev_active = jnp.asarray(act)
-            self._io_dirty = False
-        nxt, self.cache = self._decode_fused(
-            self.params, self._dev_tokens, self._dev_active, self.cache)
-        self._dev_tokens = nxt
-        # pbcheck: disable=R2 (designed sync: THE one host transfer per decode step; EOS checks need the token ids)
-        nxt_host = np.asarray(nxt)
-        self.n_decode_steps += 1
-        finished = []
-        done_slots: List[Tuple[int, ServeRequest]] = []
-        for slot, req in list(self.active.items()):
-            tok = int(nxt_host[slot])
-            req.generated.append(tok)
-            at_eos = req.eos_id is not None and tok == req.eos_id
-            if len(req.generated) >= req.max_new_tokens or at_eos:
-                req.done = True
-                finished.append(req)
-                done_slots.append((slot, req))
-                del self.active[slot]
-                self.free.append(slot)
-        if finished:
-            self._io_dirty = True        # active mask changed
-            if self.prefix_cache is not None and self._can_bucket:
-                # deposit finished prompts before their slots are reused
-                # (nothing else touches the cache within this step)
-                self._deposit_prefixes(done_slots)
-        self.decode_time_s += time.perf_counter() - t0
+        with tracing.span("pb.decode", t0=t0) as sp:
+            if sp:
+                sp.meta.update(n_active=len(self.active), cache_lens=[
+                    len(r.tokens) + len(r.generated) - 1
+                    for r in self.active.values()])
+            if self._io_dirty:
+                toks = np.zeros((self.n_slots,), np.int32)
+                act = np.zeros((self.n_slots,), bool)
+                for slot, req in self.active.items():
+                    toks[slot] = req.generated[-1]
+                    act[slot] = True
+                # committed-ness is part of the jit cache key: after a
+                # pipeline hand-off the cache is committed, and so is every
+                # step's output, so the inputs built here must be too or
+                # the next step compiles the decode a second time
+                pos = self.cache["pos"]
+                if pos.committed:
+                    toks, act = jax.device_put((toks, act), pos.sharding)
+                self._dev_tokens = jnp.asarray(toks)
+                self._dev_active = jnp.asarray(act)
+                self._io_dirty = False
+            nxt, self.cache = self._decode_fused(
+                self.params, self._dev_tokens, self._dev_active, self.cache)
+            self._dev_tokens = nxt
+            with tracing.span("pb.decode.wait"):
+                # pbcheck: disable=R2 (designed sync: THE one host transfer per decode step; EOS checks need the token ids)
+                nxt_host = np.asarray(nxt)
+            self.n_decode_steps += 1
+            finished = []
+            done_slots: List[Tuple[int, ServeRequest]] = []
+            for slot, req in list(self.active.items()):
+                tok = int(nxt_host[slot])
+                req.generated.append(tok)
+                at_eos = req.eos_id is not None and tok == req.eos_id
+                if len(req.generated) >= req.max_new_tokens or at_eos:
+                    req.done = True
+                    finished.append(req)
+                    done_slots.append((slot, req))
+                    del self.active[slot]
+                    self.free.append(slot)
+            if finished:
+                self._io_dirty = True        # active mask changed
+                if self.prefix_cache is not None and self._can_bucket:
+                    # deposit finished prompts before their slots are
+                    # reused (nothing else touches the cache in this step)
+                    self._deposit_prefixes(done_slots)
+            t1 = time.perf_counter()
+            if sp:
+                sp.end(t1, rids=[r.rid for r in finished])
+        self.decode_time_s += t1 - t0
         return finished
 
     def _deposit_prefixes(self, pairs: Sequence[Tuple[int, ServeRequest]]
@@ -846,14 +877,13 @@ class ContinuousBatcher:
         s: Dict[str, float] = {
             "n_decode_steps": float(self.n_decode_steps),
             "decode_time_s": self.decode_time_s,
-            "decode_steps_per_s": (self.n_decode_steps / self.decode_time_s
-                                   if self.decode_time_s > 0 else 0.0),
             "n_prefill_calls": float(self.n_prefill_calls),
             "n_prefill_reqs": float(self.n_prefill_reqs),
             "n_prefill_pipeline": float(self.n_prefill_pipeline),
             "n_batched_imports": float(self.n_batched_imports),
             "n_relay_scatters": float(self.n_relay_scatters),
             "n_prefill_tokens": float(self.n_prefill_tokens),
+            "n_prefill_padded_tokens": float(self.n_prefill_padded_tokens),
             "prefix_hits": float(self.prefix_hits),
             "prefix_hit_tokens": float(self.prefix_hit_tokens),
             "prefix_evictions": (
@@ -912,38 +942,49 @@ class ServingEngine:
         batch has drained (the paper's epoch semantics, Fig. 5).  Same-bucket
         requests within a policy batch prefill together in one padded call.
         Returns requests already satisfied at admission (re-submitted tails).
+        Traced, the ``pb.schedule`` span carries the admitted ``rids`` and
+        their queue ``waits`` on the engine's clock (``self.clock``).
         """
         satisfied: List[ServeRequest] = []
-        while self.batcher.free:
-            nxt = self.policy.peek_adapter(self.policy_state)
-            if nxt is None:
-                break
-            nxt_name = None if nxt == "__base__" else nxt
-            if self.batcher.active and nxt_name != self.active_adapter:
-                break  # drain before switching (epoch barrier)
-            adapter, batch = self.policy.next_batch(self.policy_state)
-            if adapter is None:
-                break
-            self._switch_adapter(adapter if adapter != "__base__" else None)
-            n_free = len(self.batcher.free)
-            if len(batch) > n_free:
-                # policy batch can exceed free slots under staggered
-                # occupancy — hand the tail back for the next tick
-                self.policy.requeue_front(self.policy_state, batch[n_free:])
-                batch = batch[:n_free]
-            groups: Dict[int, List[_PolicyItem]] = {}
-            for item in batch:
-                groups.setdefault(self.batcher.bucket_for(item.req),
-                                  []).append(item)
-            for _, items in sorted(groups.items()):
-                self.batcher.admit_batch([it.req for it in items])
-                for it in items:
-                    if it.req.first_token_at is None:
-                        it.req.first_token_at = self.clock
-                    if it.req.done:
-                        it.req.finished_at = self.clock
-                        self.completed.append(it.req)
-                        satisfied.append(it.req)
+        admitted: List[ServeRequest] = []
+        with tracing.span("pb.schedule") as sp:
+            while self.batcher.free:
+                nxt = self.policy.peek_adapter(self.policy_state)
+                if nxt is None:
+                    break
+                nxt_name = None if nxt == "__base__" else nxt
+                if self.batcher.active and nxt_name != self.active_adapter:
+                    break  # drain before switching (epoch barrier)
+                adapter, batch = self.policy.next_batch(self.policy_state)
+                if adapter is None:
+                    break
+                self._switch_adapter(None if adapter == "__base__"
+                                     else adapter)
+                n_free = len(self.batcher.free)
+                if len(batch) > n_free:
+                    # policy batch can exceed free slots under staggered
+                    # occupancy — hand the tail back for the next tick
+                    self.policy.requeue_front(self.policy_state,
+                                              batch[n_free:])
+                    batch = batch[:n_free]
+                groups: Dict[int, List[_PolicyItem]] = {}
+                for item in batch:
+                    groups.setdefault(self.batcher.bucket_for(item.req),
+                                      []).append(item)
+                for _, items in sorted(groups.items()):
+                    self.batcher.admit_batch([it.req for it in items])
+                    if sp:
+                        admitted.extend(it.req for it in items)
+                    for it in items:
+                        if it.req.first_token_at is None:
+                            it.req.first_token_at = self.clock
+                        if it.req.done:
+                            it.req.finished_at = self.clock
+                            self.completed.append(it.req)
+                            satisfied.append(it.req)
+            if admitted:
+                sp.end(rids=[r.rid for r in admitted],
+                       waits=[self.clock - r.arrival for r in admitted])
         return satisfied
 
     def step(self, now: Optional[float] = None) -> List[ServeRequest]:
