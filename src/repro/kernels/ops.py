@@ -48,13 +48,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, lens, *, k_new=None, v_new=None,
-                     slot_mask=None, block_k: int = 512,
+                     slot_mask=None, block_k: Optional[int] = None,
                      interpret: Optional[bool] = None):
     """Model-layout flash decode: q (B,1,Hq,d), caches (B,C,Hkv,d),
     lens (B,) -> (B,1,Hq,d).  Optional k/v_new (B,1,Hkv,d): the current
     token's K/V, merged in-kernel instead of read from the cache
     (zero-copy serving mode).  Optional slot_mask (B,C): per-slot cache
-    validity for ring-buffered (windowed) caches."""
+    validity for ring-buffered (windowed) caches.  ``block_k`` overrides
+    the kernel's choice from the shapes (tests only)."""
     qt = q[:, 0]                                     # (B,Hq,d)
     kt = jnp.moveaxis(k_cache, 1, 2)                 # (B,Hkv,C,d)
     vt = jnp.moveaxis(v_cache, 1, 2)

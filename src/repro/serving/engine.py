@@ -550,9 +550,15 @@ class ContinuousBatcher:
             nxt, self.cache = self._decode_fused(
                 self.params, self._dev_tokens, self._dev_active, self.cache)
             self._dev_tokens = nxt
+            kv_meta = bool(sp) and "attn" in self.cache
+            if kv_meta:
+                # the slots' lengths come to the host with the tokens
+                self.cache["pos"].copy_to_host_async()
             with tracing.span("pb.decode.wait"):
                 # pbcheck: disable=R2 (designed sync: THE one host transfer per decode step; EOS checks need the token ids)
                 nxt_host = np.asarray(nxt)
+            if kv_meta:
+                sp.meta.update(self._kv_block_meta())
             self.n_decode_steps += 1
             finished = []
             done_slots: List[Tuple[int, ServeRequest]] = []
@@ -577,6 +583,22 @@ class ContinuousBatcher:
                 sp.end(t1, rids=[r.rid for r in finished])
         self.decode_time_s += t1 - t0
         return finished
+
+    def _kv_block_meta(self) -> Dict[str, int]:
+        """The decode kernel's K/V blocks per layer in the step just run,
+        by its own rule: ``kv_blocks`` those it fetched (every slot up to
+        its valid length, free slots at the length their last request
+        left), ``kv_blocks_all`` those of the whole cache.  Called after
+        the step, before its finished requests leave ``active``."""
+        from repro.kernels import decode_attention as dec
+        k = self.cache["attn"]["k"]                   # (L, slots, C, Hkv, d)
+        C, Hkv, d = k.shape[2:]
+        bk = dec.block_k_for(C, Hkv, d, k.dtype.itemsize)
+        # pbcheck: disable=R2 (traced steps only: copied to the host beside the step's tokens, so nothing more is waited for)
+        pos = np.array(self.cache["pos"])
+        pos[list(self.active)] -= 1          # the kernel ran before the step
+        return {"kv_blocks": dec.kv_blocks(pos, C, bk),
+                "kv_blocks_all": self.n_slots * -(-C // bk)}
 
     def _deposit_prefixes(self, pairs: Sequence[Tuple[int, ServeRequest]]
                           ) -> None:

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import decode_attention as dec
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(7)
@@ -15,6 +16,16 @@ def rnd(shape, dtype, salt):
 
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def poison_past(x, lens):
+    """NaN in every cache row at or past each slot's length (model layout
+    (B, C, Hkv, d)): rows the decode kernel skips or masks must not leak
+    into its output, and 0 x NaN is NaN."""
+    C = x.shape[1]
+    dead = np.arange(C)[None, :] >= np.asarray(lens)[:, None]
+    return jnp.where(jnp.asarray(dead)[:, :, None, None],
+                     jnp.asarray(np.nan, x.dtype), x)
 
 
 @pytest.mark.parametrize("B,Sq,Sk,Hq,Hkv,d", [
@@ -43,17 +54,28 @@ def test_flash_attention_sweep(B, Sq, Sk, Hq, Hkv, d, dtype, causal, window):
 
 @pytest.mark.parametrize("B,C,Hq,Hkv,d,block_k", [
     (2, 256, 8, 8, 64, 128),
-    (3, 300, 8, 2, 64, 128),     # GQA + pad
+    (3, 300, 8, 2, 64, 128),     # GQA + ragged tail
     (1, 1024, 4, 1, 128, 512),   # MQA long cache
+    (6, 320, 4, 4, 64, 128),     # G 1, ragged tail (320 = 2.5 blocks)
+    (6, 320, 10, 2, 128, 128),   # G 5, ragged tail
+    (5, 384, 2, 1, 128, 128),    # MQA, G 2
+    (3, 1536, 16, 8, 128, None),  # qwen3-1.7b heads, block from the shapes
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_sweep(B, C, Hq, Hkv, d, block_k, dtype):
     q = rnd((B, 1, Hq, d), dtype, 4)
     k = rnd((B, C, Hkv, d), dtype, 5)
     v = rnd((B, C, Hkv, d), dtype, 6)
-    lens = jnp.asarray(
-        np.random.default_rng(0).integers(1, C + 1, size=B), jnp.int32)
-    o = ops.decode_attention(q, k, v, lens, block_k=block_k)
+    lens = np.random.default_rng(0).integers(1, C + 1, size=B)
+    # edge lengths in all but the last slot: empty, one row, a whole
+    # block, two blocks, the whole cache
+    bk = block_k or dec.block_k_for(C, Hkv, d, jnp.dtype(dtype).itemsize)
+    edges = [n for n in (0, 1, bk, 2 * bk, C) if n <= C][:B - 1]
+    lens[:len(edges)] = edges
+    lens = jnp.asarray(lens, jnp.int32)
+    o = ops.decode_attention(q, poison_past(k, lens), poison_past(v, lens),
+                             lens, block_k=block_k)
+    assert np.isfinite(np.asarray(o, np.float32)).all()
     r = ref.decode_attention_ref(q[:, 0], jnp.moveaxis(k, 1, 2),
                                  jnp.moveaxis(v, 1, 2), lens)
     np.testing.assert_allclose(np.asarray(o[:, 0], np.float32),
@@ -63,7 +85,9 @@ def test_decode_attention_sweep(B, C, Hq, Hkv, d, block_k, dtype):
 
 @pytest.mark.parametrize("B,C,Hq,Hkv,d", [
     (3, 256, 8, 2, 64),
-    (2, 300, 4, 4, 32),              # pad path
+    (2, 300, 4, 4, 32),              # ragged tail
+    (5, 320, 10, 2, 128),            # G 5, ragged tail
+    (4, 256, 4, 1, 64),              # MQA
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_merged_new_token(B, C, Hq, Hkv, d, dtype):
@@ -78,8 +102,11 @@ def test_decode_attention_merged_new_token(B, C, Hq, Hkv, d, dtype):
     lens = np.random.default_rng(1).integers(1, C - 1, size=B)
     lens[0] = 0                       # slot fresh out of (empty) prefill
     lens[-1] = C - 1                  # slot about to fill its cache
+    if B >= 4:
+        lens[1:3] = (1, 128)          # one row; exactly one whole block
     lens = jnp.asarray(lens, jnp.int32)
-    o = ops.decode_attention(q, k, v, lens, k_new=kn, v_new=vn, block_k=128)
+    o = ops.decode_attention(q, poison_past(k, lens), poison_past(v, lens),
+                             lens, k_new=kn, v_new=vn, block_k=128)
     # oracle: write the new token into the cache, then plain ragged decode
     bidx = jnp.arange(B)
     kw = k.at[bidx, lens].set(kn[:, 0])
@@ -93,7 +120,9 @@ def test_decode_attention_merged_new_token(B, C, Hq, Hkv, d, dtype):
 
 @pytest.mark.parametrize("B,C,Hq,Hkv,d,block_k", [
     (3, 40, 8, 2, 64, 16),           # GQA, mask straddles block edges
-    (2, 300, 4, 4, 32, 128),         # pad path
+    (2, 300, 4, 4, 32, 128),         # ragged tail
+    (4, 320, 10, 2, 128, 128),       # G 5, ragged tail
+    (3, 256, 4, 1, 64, 128),         # MQA
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("merge_new", [False, True])
@@ -112,7 +141,8 @@ def test_decode_attention_slot_mask(B, C, Hq, Hkv, d, block_k, dtype,
     if merge_new:
         kwargs["k_new"] = rnd((B, 1, Hkv, d), dtype, 53)
         kwargs["v_new"] = rnd((B, 1, Hkv, d), dtype, 54)
-    o = ops.decode_attention(q, k, v, lens, slot_mask=jnp.asarray(sm),
+    o = ops.decode_attention(q, poison_past(k, lens), poison_past(v, lens),
+                             lens, slot_mask=jnp.asarray(sm),
                              block_k=block_k, **kwargs)
     if merge_new:
         # oracle: write the new token at the ring slot (pos % C), mark the
@@ -132,6 +162,27 @@ def test_decode_attention_slot_mask(B, C, Hq, Hkv, d, block_k, dtype,
     np.testing.assert_allclose(np.asarray(o[:, 0], np.float32),
                                np.asarray(r, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("C,Hkv,d,itemsize,want", [
+    (1536, 8, 128, 2, 256),      # qwen3-1.7b: six 512 KiB blocks
+    (3200, 8, 128, 2, 256),      # qwen2.5-14b: a ragged thirteenth block
+    (96, 8, 128, 2, 96),         # short cache: one whole block
+    (4096, 4, 128, 2, 512),      # fewer KV heads, more rows
+    (4096, 4, 128, 4, 256),      # f32 halves the rows
+    (4096, 64, 256, 2, 128),     # wide heads: never under 128 rows
+])
+def test_decode_block_k_follows_the_shapes(C, Hkv, d, itemsize, want):
+    bk = dec.block_k_for(C, Hkv, d, itemsize)
+    assert bk == want
+    assert bk == C or bk % 128 == 0          # Mosaic's (8, 128) rule
+
+
+def test_decode_kv_blocks_counts_the_valid_blocks():
+    # 0 and 1 rows still fetch the first block; lengths past C are C
+    assert dec.kv_blocks([0, 1, 512, 513, 1536, 2000], 1536, 512) == \
+        1 + 1 + 1 + 2 + 3 + 3
+    assert dec.kv_blocks(np.array([3200, 3073, 3072]), 3200, 512) == 20
 
 
 def test_windowed_decode_step_pallas_matches_xla():

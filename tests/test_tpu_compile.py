@@ -3,13 +3,16 @@
 Interpret mode on the CPU does not check Mosaic's tiling rules, so every
 kernel test can pass while the chip refuses the kernel.  These tests hand
 the serving path's kernels and the full-width decode step to the TPU
-compiler at qwen3-1.7b widths; a refusal fails here, at no chip time.
+compiler at qwen3-1.7b widths, and the decode kernel at qwen2.5-14b's
+too (one pipeline stage's slots and cache); a refusal fails here, at no
+chip time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers that
 collected different tests would run none.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +26,9 @@ from repro.models.attention import decode_attn_impl
 
 CFG = get_arch("qwen3-1.7b")
 B = 4
+# (slots, Hq, Hkv, d) of each decode the benchmark serves
+QWEN3 = (B, CFG.n_heads, CFG.n_kv_heads, CFG.resolved_head_dim)
+PP4 = (4, 40, 8, 128)           # qwen2.5-14b, G = 5
 
 
 @pytest.fixture(scope="module")
@@ -59,10 +65,13 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("C", [96, 256, 2048])
+@pytest.mark.parametrize("C,widths", [
+    *(pytest.param(C, QWEN3, id=str(C)) for C in (96, 256, 2048)),
+    pytest.param(3200, PP4, id="3200-qwen2.5-14b"),   # ragged: 3200 % 512
+])
 @pytest.mark.parametrize("form", ["plain", "merged", "slot_masked"])
-def test_decode_kernel_compiles_for_v5e(one_chip, form, C):
-    Hq, Hkv, d = CFG.n_heads, CFG.n_kv_heads, CFG.resolved_head_dim
+def test_decode_kernel_compiles_for_v5e(one_chip, form, C, widths):
+    B, Hq, Hkv, d = widths
     bf16 = jnp.bfloat16
     q = _struct((B, 1, Hq, d), bf16, one_chip)
     kv = _struct((B, C, Hkv, d), bf16, one_chip)
@@ -83,6 +92,10 @@ def test_decode_kernel_compiles_for_v5e(one_chip, form, C):
                 interpret=False),
             q, kv, kv, lens, new, new, mask)
     assert "tpu_custom_call" in hlo
+    # the trace's reduction finds the kernel by this name, and the cache
+    # reaches it unpadded (a ragged C is a partial last block)
+    assert re.search(r"%decode_attention\.\d+ = .*custom-call\(", hlo)
+    assert "pad(" not in hlo
 
 
 def test_lora_merge_compiles_for_v5e(one_chip):

@@ -208,6 +208,37 @@ def test_queue_waits_are_on_the_engines_own_clock(tiny):
     assert got == [([0], [0.0]), ([1], [2.0])]
 
 
+def test_decode_span_counts_the_kernels_kv_blocks(tiny, monkeypatch):
+    """``kv_blocks`` is what the decode kernel fetches per layer: every
+    slot up to its length, a free slot at the length its last request
+    left, an unused slot its first block."""
+    from repro.kernels import decode_attention as dec
+    cfg, params = tiny
+    # 128-row blocks at these widths (2 KV heads of 16, f32): a 400-row
+    # cache is 4 blocks, the last one ragged
+    monkeypatch.setattr(dec, "BLOCK_BYTES", 2 * 16 * 4 * 128)
+    srv = ServingEngine(cfg, params, n_slots=4, max_len=400)
+    assert dec.block_k_for(400, 2, 16, srv.batcher.cache["attn"]["k"]
+                           .dtype.itemsize) == 128
+    rng = np.random.default_rng(1)
+    # A decodes at lengths 127 and 128 (one block) and leaves its slot at
+    # 129 (two); B stays within one block; two slots are never used
+    srv.submit(ServeRequest(0, rng.integers(0, 250, size=127),
+                            max_new_tokens=3, arrival=0.0))
+    srv.submit(ServeRequest(1, rng.integers(0, 250, size=10),
+                            max_new_tokens=6, arrival=0.0))
+    tracing.enable()
+    srv.run()
+    tracing.disable()
+    decodes = [r for r in tracing.spans() if r.name == "pb.decode"]
+    assert [d.meta["kv_blocks"] for d in decodes] == [4, 4, 5, 5, 5]
+    assert {d.meta["kv_blocks_all"] for d in decodes} == {4 * 4}
+    # the same count from the device's own lengths after the run
+    pos = np.asarray(srv.batcher.cache["pos"])
+    assert sorted(pos.tolist()) == [0, 0, 10 + 5, 127 + 2]
+    assert dec.kv_blocks(pos, 400, 128) == 5
+
+
 def test_decode_time_is_the_decode_spans_time(tiny):
     cfg, params = tiny
     srv = ServingEngine(cfg, params, n_slots=2, max_len=64)
