@@ -35,7 +35,15 @@ class ArchConfig:
     # --- attention options -------------------------------------------------
     qk_norm: bool = False          # RMSNorm on q/k per-head (qwen3)
     qkv_bias: bool = False         # bias on qkv projections (qwen2.5 family)
-    attn_window: int = 0           # 0 = full; >0 = sliding local window
+    attn_window: int = 0           # 0 = full; >0 = sliding window over a
+                                   # ring-buffer cache of that capacity
+    # per-layer sliding windows, repeated over the stack (0 = full): masks
+    # over a full-length cache, so every layer keeps every position
+    layer_windows: Tuple[int, ...] = ()
+    layer_rope: Tuple[bool, ...] = ()     # per-layer RoPE, repeated; () = all
+    attn_out_gate: bool = False    # o *= sigmoid(norm(x) @ wg) before wo
+    sandwich_norm: bool = False    # also norm each branch's output (post/*)
+    embed_scale: bool = False      # embeddings times sqrt(d_model) (muP)
     rope_theta: float = 1e6
     mrope: bool = False            # multimodal section-wise rotary (qwen2-vl)
     mrope_sections: Tuple[int, ...] = (16, 24, 24)  # t,h,w splits of head_dim/2
@@ -46,8 +54,16 @@ class ArchConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0              # per-expert hidden dim
-    capacity_factor: float = 1.25
-    serving_capacity_factor: float = 2.0
+    n_dense_layers: int = 0        # leading dense layers before the MoE ones
+    # expert parallelism: the router scores ``router_experts`` (0 = all
+    # ``n_experts``), and this chip holds experts [expert_offset,
+    # expert_offset + n_experts) of them
+    router_experts: int = 0
+    expert_offset: int = 0
+    router_score: str = "softmax"  # softmax | sigmoid
+    router_bias: bool = False      # per-expert bias added for selection only
+    route_scale: float = 1.0       # routing weights: w / sum(w) * route_scale
+    shared_expert_gate: bool = True   # sigmoid(x @ gate) on the shared output
     router_aux_coef: float = 0.01
 
     # --- SSM (mamba2 / SSD) --------------------------------------------------
@@ -69,6 +85,14 @@ class ArchConfig:
     dtype: str = "bfloat16"
     source: str = ""               # public provenance [source; tier]
 
+    def __post_init__(self):
+        # JSON gives lists; the config is a static jit argument (hashable)
+        for f in ("layer_windows", "layer_rope", "mrope_sections",
+                  "block_pattern"):
+            v = getattr(self, f)
+            if isinstance(v, list):
+                object.__setattr__(self, f, tuple(v))
+
     # ------------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
@@ -83,6 +107,20 @@ class ArchConfig:
         """Vocab padded to a multiple of 256 (Megatron-style) so the vocab
         dim shards cleanly over any mesh axis we use (<=256-way)."""
         return ((self.vocab_size + 255) // 256) * 256
+
+    @property
+    def n_router_experts(self) -> int:
+        return self.router_experts or self.n_experts
+
+    def layer_window(self, i: int) -> int:
+        """Layer ``i``'s sliding window over its full-length cache (0 =
+        full attention)."""
+        w = self.layer_windows
+        return w[i % len(w)] if w else 0
+
+    def layer_uses_rope(self, i: int) -> bool:
+        r = self.layer_rope
+        return bool(r[i % len(r)]) if r else True
 
     @property
     def d_inner(self) -> int:
@@ -117,7 +155,8 @@ class ArchConfig:
         if self.family == "ssm":
             return ["ssm"] * self.n_layers
         if self.family == "moe":
-            return ["moe"] * self.n_layers
+            n = self.n_dense_layers
+            return ["attn"] * n + ["moe"] * (self.n_layers - n)
         return ["attn"] * self.n_layers
 
     # ------------------------------------------------------------------
@@ -132,6 +171,9 @@ class ArchConfig:
         kinds = self.layer_kinds()
         for kind in kinds:
             n += 2 * D  # the two pre-norms (single for ssm, counted anyway)
+            if kind in ("attn", "moe"):
+                n += 2 * D if self.sandwich_norm else 0
+                n += D * self.n_heads * hd if self.attn_out_gate else 0
             if kind == "attn":
                 q = D * self.n_heads * hd + (self.n_heads * hd if self.qkv_bias else 0)
                 kv = 2 * (D * self.n_kv_heads * hd + (self.n_kv_heads * hd if self.qkv_bias else 0))
@@ -145,7 +187,8 @@ class ArchConfig:
                 kv = 2 * D * self.n_kv_heads * hd
                 o = self.n_heads * hd * D
                 n += q + kv + o
-                n += D * self.n_experts  # router
+                E = self.n_router_experts
+                n += D * E + (E if self.router_bias else 0)   # router
                 n += self.n_experts * 3 * D * self.moe_d_ff
                 n += self.n_shared_experts * 3 * D * self.moe_d_ff
             elif kind == "ssm":
@@ -159,7 +202,6 @@ class ArchConfig:
                 w = self.lru_width or D
                 n += 2 * D * w      # gate branch + x branch
                 n += w * self.ssm_conv
-                n += 2 * w * w // 1 if False else 0
                 n += 2 * w          # input gate, recurrence gate (diagonal blocks approximated dense below)
                 n += 2 * w * w // 16  # block-diagonal gates (16 blocks) approx
                 n += w              # Lambda
@@ -173,8 +215,9 @@ class ArchConfig:
             return self.param_count()
         D = self.d_model
         dense = self.param_count()
-        all_exp = self.n_layers * self.n_experts * 3 * D * self.moe_d_ff
-        act_exp = self.n_layers * self.top_k * 3 * D * self.moe_d_ff
+        n_moe = self.n_layers - self.n_dense_layers
+        all_exp = n_moe * self.n_experts * 3 * D * self.moe_d_ff
+        act_exp = n_moe * self.top_k * 3 * D * self.moe_d_ff
         return int(dense - all_exp + act_exp)
 
     def reduced(self, **overrides) -> "ArchConfig":
@@ -191,7 +234,12 @@ class ArchConfig:
         if self.family == "moe":
             kw.update(n_experts=4, top_k=min(self.top_k, 2), moe_d_ff=32,
                       n_shared_experts=min(self.n_shared_experts, 1),
-                      capacity_factor=8.0)  # dropless at test scale
+                      router_experts=0, expert_offset=0)
+            if self.n_dense_layers:   # one dense layer, then a window period
+                kw.update(n_dense_layers=1, n_layers=min(self.n_layers, 5))
+        if self.layer_windows:
+            kw.update(layer_windows=tuple(min(w, 16)
+                                          for w in self.layer_windows))
         if self.family == "ssm":
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
         if self.mrope:
@@ -241,6 +289,7 @@ ARCH_IDS: List[str] = [
     "qwen2-moe-a2.7b",
     "phi3.5-moe-42b-a6.6b",
     "recurrentgemma-2b",
+    "trinity-mini",
     # the paper's own evaluation family (OPT-1.3B-like) used by benchmarks
     "pipeboost-opt-1.3b",
 ]
@@ -256,6 +305,7 @@ _MODULE_FOR: Dict[str, str] = {
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b",
     "recurrentgemma-2b": "recurrentgemma_2b",
+    "trinity-mini": "trinity_mini",
     "pipeboost-opt-1.3b": "pipeboost_opt_1_3b",
 }
 

@@ -31,11 +31,8 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn_lib
 from repro.models import mamba2, transformer
-from repro.models.transformer import (_apply_mlp, _apply_norm, _project_qkv,
-                                      _rope, attn_cache_capacity,
-                                      rec_layer_fwd)
-from repro.models import moe as moe_lib
-from repro.models.layers import _ACTS
+from repro.models.transformer import (_apply_norm, _project_qkv, _rope,
+                                      attn_cache_capacity, rec_layer_fwd)
 
 
 def _layer_params(params, kind: str, idx: int):
@@ -68,6 +65,10 @@ def reconstruct_cache(cfg: ArchConfig, params, batch: Dict,
     ``batch`` carries the merged sequence ({"tokens": (B, S)} or embeds).
     The returned cache is exactly equal (up to fp) to a fresh prefill cache.
     """
+    # the Q-only path below rebuilds attention without per-layer windows,
+    # RoPE flags, the output gate or the branch norms
+    assert not (cfg.layer_windows or cfg.layer_rope or cfg.attn_out_gate
+                or cfg.sandwich_norm), f"{cfg.name}: no rebuild path"
     x, positions = transformer.embed_tokens(cfg, params, batch)
     B, S = x.shape[:2]
     max_len = max_len or S
@@ -118,13 +119,7 @@ def reconstruct_cache(cfg: ArchConfig, params, batch: Dict,
                         q, kc[:, :S], vc[:, :S], causal=True, window=0)
                     o = attn_lib.finalize_partial(p, q.dtype)
                 o = o.reshape(B, S, -1) @ p_l["wo"]
-                x = x + o
-                h2 = _apply_norm(cfg, p_l["ln2"], x)
-                if "router" in p_l["mlp"]:
-                    y, _ = moe_lib.moe_mlp(cfg, p_l["mlp"], h2, _ACTS[cfg.act])
-                else:
-                    y = _apply_mlp(cfg, p_l["mlp"], h2)
-                x = x + y
+                x, _, _ = transformer._ffn_branch(cfg, p_l, x + o)
                 stats["kv_reused"] += 1
                 stats["q_only_tokens"] += S
             else:

@@ -58,6 +58,15 @@ def stage_mesh(n_stages: int, n_data: Optional[int] = None) -> Mesh:
     return Mesh(arr, ("data", "stage"))
 
 
+def belt_param_spec(path, leaf) -> P:
+    """Where the belt holds a parameter: stacked block leaves split over
+    'stage' by layer, embed/head/final_norm replicated per stage."""
+    names = [getattr(p, "key", None) for p in path]
+    if "blocks" in names and leaf.ndim >= 1:
+        return P("stage", *([None] * (leaf.ndim - 1)))
+    return P()
+
+
 def _uniform_kind(cfg: ArchConfig) -> str:
     kinds = set(cfg.layer_kinds())
     if len(kinds) != 1:
@@ -214,14 +223,8 @@ def build_pipeline_prefill(cfg: ArchConfig, *, n_stages: int, n_micro: int,
         return logits, state
 
     # --- shard_map wiring --------------------------------------------------
-    def pspec_params(path, leaf):
-        names = [getattr(p, "key", None) for p in path]
-        if "blocks" in names and leaf.ndim >= 1:
-            return P("stage", *([None] * (leaf.ndim - 1)))
-        return P()          # embed/head/final_norm replicated per stage
-
     def f(params, batch):
-        pspecs = jax.tree_util.tree_map_with_path(pspec_params, params)
+        pspecs = jax.tree_util.tree_map_with_path(belt_param_spec, params)
         bspecs = jax.tree.map(
             lambda a: P("data", *([None] * (a.ndim - 1))), batch)
         if return_cache:
@@ -301,11 +304,10 @@ def build_pipeline_prefill_seqchunk(cfg: ArchConfig, *, n_stages: int,
 
             def layer(x, per):
                 p_l, kv_l = per
-                from repro.models.transformer import (_apply_norm, _ACTS,
-                                                      _apply_mlp,
+                from repro.models.transformer import (_apply_norm,
+                                                      _ffn_branch,
                                                       _project_qkv, _rope)
                 from repro.models import attention as attn_lib
-                from repro.models import moe as moe_lib
                 h = _apply_norm(cfg, p_l["ln1"], x)
                 q, k, v = _project_qkv(cfg, p_l, h)
                 q = _rope(cfg, q, positions)
@@ -321,14 +323,8 @@ def build_pipeline_prefill_seqchunk(cfg: ArchConfig, *, n_stages: int,
                         kv_valid_len=q_off + chunk,
                         block_k=max(chunk, 1024)), q.dtype)
                 o = o.reshape(B, chunk, -1) @ p_l["wo"]
-                x = x + o
-                h2 = _apply_norm(cfg, p_l["ln2"], x)
-                if "router" in p_l["mlp"]:
-                    y, _ = moe_lib.moe_mlp(cfg, p_l["mlp"], h2,
-                                           _ACTS[cfg.act])
-                else:
-                    y = _apply_mlp(cfg, p_l["mlp"], h2)
-                return x + y, kv_l
+                x, _, _ = _ffn_branch(cfg, p_l, x + o)
+                return x, kv_l
 
             x_out, kv = jax.lax.scan(layer, x_in, (blocks, kv))
             # last stage, last chunk: final norm + last-token unembed
@@ -351,14 +347,8 @@ def build_pipeline_prefill_seqchunk(cfg: ArchConfig, *, n_stages: int,
         logits_buf = jax.lax.psum(logits_buf, "stage")
         return logits_buf
 
-    def pspec_params(path, leaf):
-        names = [getattr(p, "key", None) for p in path]
-        if "blocks" in names and leaf.ndim >= 1:
-            return P("stage", *([None] * (leaf.ndim - 1)))
-        return P()
-
     def f(params, batch):
-        pspecs = jax.tree_util.tree_map_with_path(pspec_params, params)
+        pspecs = jax.tree_util.tree_map_with_path(belt_param_spec, params)
         bspecs = jax.tree.map(
             lambda a: P("data", *([None] * (a.ndim - 1))), batch)
         return jax.shard_map(body, mesh=mesh, in_specs=(pspecs, bspecs),
@@ -402,14 +392,9 @@ def build_pipeline_cell(cfg: ArchConfig, shape: ShapeConfig, *,
         f = build_pipeline_prefill(cfg, n_stages=n_stages, n_micro=n_micro,
                                    mesh=mesh, seq_len=shape.seq_len)
 
-    def pspec_params(path, leaf):
-        names = [getattr(p, "key", None) for p in path]
-        if "blocks" in names and leaf.ndim >= 1:
-            return P("stage", *([None] * (leaf.ndim - 1)))
-        return P()
-
     pshard = jax.tree_util.tree_map_with_path(
-        lambda pa, l: NamedSharding(mesh, pspec_params(pa, l)), params_struct)
+        lambda pa, l: NamedSharding(mesh, belt_param_spec(pa, l)),
+        params_struct)
     bshard = jax.tree.map(
         lambda a: NamedSharding(mesh, P("data", *([None] * (a.ndim - 1)))),
         batch_struct)

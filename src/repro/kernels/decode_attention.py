@@ -24,6 +24,13 @@ is a partial last block whose rows past C hold whatever the copy left
 there, and both the scores and the V rows at or past ``lens`` (clamped
 to C) are masked, since 0 x NaN is NaN.
 
+Sliding windows over a full-length cache: pass ``lo`` (B,), each slot's
+first row in the window.  It is prefetched like ``lens``; the index maps
+clamp the block index from below to the block holding ``lo`` as they
+clamp it from above, so blocks wholly before the window are neither
+fetched nor computed, and rows under ``lo`` are masked like rows at or
+past ``lens``.  Without ``lo`` the kernel is unchanged.
+
 Zero-copy serving mode: pass ``k_new``/``v_new`` (the current token's K/V,
 not yet written to the cache) and the kernel folds them into the final
 split-K block's online-softmax state — the cache is only *read*, so the
@@ -37,7 +44,7 @@ holds the evicted, out-of-window entry and must not be attended).  The
 mask rides the same split-K blocking as K/V, so the windowed zero-copy
 path no longer has to fall back to the XLA lowering.
 
-Layouts: q (B, Hq, d); k/v (B, Hkv, C, d); lens (B,) int32;
+Layouts: q (B, Hq, d); k/v (B, Hkv, C, d); lens (B,) int32; lo (B,) int32;
 k/v_new (B, Hkv, 1, d); slot_mask (B, C) bool/int -> out (B, Hq, d).
 
 Mosaic tiling: the last two dims of every block must be multiples of
@@ -73,16 +80,22 @@ def block_k_for(C: int, Hkv: int, d: int, itemsize: int) -> int:
     return min(C, max(rows, 128))
 
 
-def kv_blocks(lens, C: int, block_k: int) -> int:
-    """K/V blocks the kernel fetches for slots of valid lengths ``lens``:
-    each slot's blocks up to its last valid one, and at least its first."""
+def kv_blocks(lens, C: int, block_k: int, lo=None) -> int:
+    """K/V blocks the kernel fetches for slots of valid lengths ``lens``
+    (and first rows ``lo``): each slot's blocks from the one holding its
+    first row up to its last valid one, and at least one."""
     n = -(-np.minimum(np.asarray(lens, np.int64), C) // block_k)
+    if lo is not None:
+        n = n - np.asarray(lo, np.int64) // block_k
     return int(np.maximum(n, 1).sum())
 
 
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, scale: float,
-                   block_k: int, n_k: int, merge_new: bool,
-                   masked: bool):
+def _decode_kernel(lens_ref, *rest, scale: float, block_k: int, n_k: int,
+                   merge_new: bool, masked: bool, bounded: bool):
+    lo_ref = None
+    if bounded:
+        lo_ref, rest = rest[0], rest[1:]
+    q_ref, k_ref, v_ref, *rest = rest
     smask_ref = None
     if masked:
         smask_ref, rest = rest[0], rest[1:]
@@ -103,7 +116,12 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(ki * block_k < valid)
+    live = ki * block_k < valid
+    if bounded:
+        first = lo_ref[b]
+        live = live & ((ki + 1) * block_k > first)
+
+    @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale          # (Hkv, G, d)
         k = k_ref[0].astype(jnp.float32)                  # (Hkv, Bk, d)
@@ -111,10 +129,12 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         start = ki * block_k
         # rows past lens (and past C in a ragged tail) may hold anything:
         # 0 x NaN is NaN, so V is zeroed there as well as the scores masked
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, block_k, 1), 1)
-        v = jnp.where(start + row < valid, v, 0.0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_k), 2)
-        col_ok = start + col < valid                      # (1, 1, Bk)
+        row = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k, 1), 1)
+        col = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_k), 2)
+        row_ok, col_ok = row < valid, col < valid         # col_ok (1, 1, Bk)
+        if bounded:
+            row_ok, col_ok = row_ok & (row >= first), col_ok & (col >= first)
+        v = jnp.where(row_ok, v, 0.0)
         if masked:
             # per-slot validity (ring buffers): ANDed with the prefix-length
             # mask, exactly like the XLA lowering's kv_slot_mask
@@ -156,6 +176,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      lens: jnp.ndarray, *, k_new: Optional[jnp.ndarray] = None,
                      v_new: Optional[jnp.ndarray] = None,
                      slot_mask: Optional[jnp.ndarray] = None,
+                     lo: Optional[jnp.ndarray] = None,
                      scale: Optional[float] = None,
                      block_k: Optional[int] = None,
                      interpret: bool = True) -> jnp.ndarray:
@@ -164,44 +185,52 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     With ``k_new``/``v_new`` (B, Hkv, 1, d) the current token is attended
     as if written at position ``lens`` (zero-copy serving mode).  With
     ``slot_mask`` (B, C) only slots where the mask is nonzero are attended
-    (ring-buffer eviction), ANDed with the ``lens`` prefix mask.
+    (ring-buffer eviction), ANDed with the ``lens`` prefix mask.  With
+    ``lo`` (B,) rows under ``lo`` are neither attended nor fetched.
     ``block_k`` overrides ``block_k_for``'s choice (tests only)."""
     B, Hq, d = q.shape
     _, Hkv, C, _ = k.shape
     G = Hq // Hkv
     merge_new = k_new is not None
     masked = slot_mask is not None
+    bounded = lo is not None
     scale = scale if scale is not None else d ** -0.5
     if block_k is None:
         block_k = block_k_for(C, Hkv, d, k.dtype.itemsize)
     block_k = min(block_k, C)
     n_k = pl.cdiv(C, block_k)
     lens = jnp.minimum(lens.astype(jnp.int32), C)
+    prefetch = [lens]
+    if bounded:
+        prefetch.append(jnp.minimum(lo.astype(jnp.int32), lens))
 
-    def kv_block(b, ki, lens):
-        # clamp to the slot's last valid block: a repeated block index
-        # issues no copy, so dead blocks are never fetched
+    def kv_block(b, ki, lens, *lo):
+        # clamp to the slot's last valid block (and from below to the
+        # block holding its first row): a repeated block index issues no
+        # copy, so dead blocks are never fetched
         last = jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
+        if lo:
+            ki = jnp.maximum(ki, jnp.minimum(lo[0][b] // block_k, last))
         return jnp.minimum(ki, last)
 
     kernel = functools.partial(_decode_kernel, scale=scale,
                                block_k=block_k, n_k=n_k, merge_new=merge_new,
-                               masked=masked)
-    heads = pl.BlockSpec((1, Hkv, G, d), lambda b, ki, lens: (b, 0, 0, 0))
+                               masked=masked, bounded=bounded)
+    heads = pl.BlockSpec((1, Hkv, G, d), lambda b, ki, *_: (b, 0, 0, 0))
     kv = pl.BlockSpec((1, Hkv, block_k, d),
-                      lambda b, ki, lens: (b, 0, kv_block(b, ki, lens), 0))
+                      lambda b, ki, *p: (b, 0, kv_block(b, ki, *p), 0))
     in_specs = [heads, kv, kv]
     inputs = [q.reshape(B, Hkv, G, d), k, v]
     if masked:
         in_specs.append(pl.BlockSpec(
-            (1, 1, block_k), lambda b, ki, lens: (b, 0, kv_block(b, ki, lens))))
+            (1, 1, block_k), lambda b, ki, *p: (b, 0, kv_block(b, ki, *p))))
         inputs.append(jnp.asarray(slot_mask, jnp.int32)[:, None, :])
     if merge_new:
-        new = pl.BlockSpec((1, Hkv, 1, d), lambda b, ki, lens: (b, 0, 0, 0))
+        new = pl.BlockSpec((1, Hkv, 1, d), lambda b, ki, *_: (b, 0, 0, 0))
         in_specs += [new, new]
         inputs += [k_new, v_new]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, n_k),
         in_specs=in_specs,
         out_specs=heads,
@@ -216,5 +245,5 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d), q.dtype),
         interpret=interpret,
-    )(lens, *inputs)
+    )(*prefetch, *inputs)
     return out.reshape(B, Hq, d)

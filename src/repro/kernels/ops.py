@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 On the CPU kernels execute in interpret mode (Python semantics, exact
-math); on TPU ``decode_attention`` and ``lora_merge`` compile to Mosaic,
-while ``ssd_scan`` and ``rglru_scan`` do not (see their modules).
+math); on TPU ``decode_attention``, ``moe_gmm`` and ``lora_merge``
+compile to Mosaic, while ``ssd_scan`` and ``rglru_scan`` do not (see their
+modules).
 ``interpret`` is resolved from the backend unless forced.  Layout
 adapters translate from the model zoo's (B, S, H, d) convention to the
 kernels' (B, H, S, d).
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import lora_merge as _lm
+from repro.kernels import moe_gmm as _gmm
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssd_scan as _ssd
 
@@ -48,13 +50,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k_cache, v_cache, lens, *, k_new=None, v_new=None,
-                     slot_mask=None, block_k: Optional[int] = None,
+                     slot_mask=None, lo=None, block_k: Optional[int] = None,
                      interpret: Optional[bool] = None):
     """Model-layout flash decode: q (B,1,Hq,d), caches (B,C,Hkv,d),
     lens (B,) -> (B,1,Hq,d).  Optional k/v_new (B,1,Hkv,d): the current
     token's K/V, merged in-kernel instead of read from the cache
     (zero-copy serving mode).  Optional slot_mask (B,C): per-slot cache
-    validity for ring-buffered (windowed) caches.  ``block_k`` overrides
+    validity for ring-buffered (windowed) caches.  Optional lo (B,): each
+    slot's first row in a sliding window.  ``block_k`` overrides
     the kernel's choice from the shapes (tests only)."""
     qt = q[:, 0]                                     # (B,Hq,d)
     kt = jnp.moveaxis(k_cache, 1, 2)                 # (B,Hkv,C,d)
@@ -62,9 +65,20 @@ def decode_attention(q, k_cache, v_cache, lens, *, k_new=None, v_new=None,
     kn = None if k_new is None else jnp.moveaxis(k_new, 1, 2)
     vn = None if v_new is None else jnp.moveaxis(v_new, 1, 2)
     o = _dec.decode_attention(qt, kt, vt, lens, k_new=kn, v_new=vn,
-                              slot_mask=slot_mask, block_k=block_k,
+                              slot_mask=slot_mask, lo=lo, block_k=block_k,
                               interpret=_interpret(interpret))
     return o[:, None]
+
+
+@functools.partial(jax.jit, static_argnames=("act", "tm", "tf", "interpret"))
+def moe_gmm(x, w_gate, w_up, w_down, group_sizes, *, act=jax.nn.silu,
+            tm: Optional[int] = None, tf: Optional[int] = None,
+            interpret: Optional[bool] = None):
+    """Grouped expert FFN over rows sorted by expert: x (M, D), w_gate /
+    w_up (G, D, F), w_down (G, F, D), group_sizes (G,) -> (M, D).  Rows at
+    or past sum(group_sizes) are unspecified."""
+    return _gmm.moe_gmm(x, w_gate, w_up, w_down, group_sizes, act=act,
+                        tm=tm, tf=tf, interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -61,14 +61,17 @@ def finalize_partial(p: AttnPartial, dtype) -> jnp.ndarray:
     return (p.acc / l[..., None]).astype(dtype)
 
 
-def _block_mask(q_pos, k_pos, *, causal: bool, window: int):
-    """(Bq, Bk) bool mask: True = attend."""
+def _block_mask(q_pos, k_pos, *, causal: bool, window):
+    """(Bq, Bk) bool mask: True = attend.  ``window`` is an int or a
+    traced scalar (a layer's window in a scan); 0 is full attention."""
     dq = q_pos[:, None]
     dk = k_pos[None, :]
     ok = jnp.ones((q_pos.shape[0], k_pos.shape[0]), bool)
     if causal:
         ok = ok & (dk <= dq)
-    if window > 0:
+    if not isinstance(window, int):
+        ok = ok & ((window <= 0) | (dk > dq - window))
+    elif window > 0:
         ok = ok & (dk > dq - window)
     return ok
 
@@ -296,7 +299,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
 
 
 def decode_attention_merged(q, k_cache, v_cache, cache_len, k_new, v_new, *,
-                            kv_slot_mask=None,
+                            kv_slot_mask=None, kv_lo=None,
                             scale: Optional[float] = None) -> jnp.ndarray:
     """Zero-copy decode attention: the current token's K/V are merged as an
     online-softmax partial instead of being written into the cache first.
@@ -314,6 +317,10 @@ def decode_attention_merged(q, k_cache, v_cache, cache_len, k_new, v_new, *,
     entry and must not be attended).  The mask rides the Pallas kernel's
     split-K blocking too, so the windowed path no longer pins to the XLA
     lowering.
+
+    ``kv_lo`` (B,) int: each slot's first cache row inside a sliding
+    window over a full-length cache; rows under it are not attended (and
+    the Pallas kernel does not fetch their blocks).
     """
     if scale is None and _use_pallas_decode():
         from repro.kernels import ops
@@ -322,7 +329,11 @@ def decode_attention_merged(q, k_cache, v_cache, cache_len, k_new, v_new, *,
             jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
         return ops.decode_attention(q, k_cache, v_cache, lens,
                                     k_new=k_new, v_new=v_new,
-                                    slot_mask=kv_slot_mask)
+                                    slot_mask=kv_slot_mask, lo=kv_lo)
+    if kv_lo is not None:
+        in_win = jnp.arange(k_cache.shape[1])[None, :] >= kv_lo[:, None]
+        kv_slot_mask = in_win if kv_slot_mask is None \
+            else kv_slot_mask & in_win
     p_old = attention_partial(q, k_cache, v_cache, causal=False, window=0,
                               kv_valid_len=cache_len,
                               kv_slot_mask=kv_slot_mask,

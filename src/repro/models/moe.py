@@ -1,155 +1,145 @@
-"""Mixture-of-Experts FFN — shared + routed top-k, capacity-based dense
-dispatch (GShard-style), pure JAX.
+"""Mixture-of-Experts FFN — dropless, routed over every expert of the
+deployment, computing only the experts held here.
 
-TPU adaptation (DESIGN.md §2): dispatch/combine are dense einsums over a
-capacity-bounded (T, E, C) tensor — MXU-friendly, no data-dependent shapes —
-instead of a GPU-style scatter/grouped-GEMM.  Expert weights are stacked
-(E, ...) so they shard like any other tensor; expert-parallel all-to-all is
-an optional optimization lever (see EXPERIMENTS.md §Perf), not a
-correctness requirement.
+Expert parallelism, one chip's share: the router scores all
+``cfg.n_router_experts`` experts and picks ``top_k`` per token; this layer
+holds experts ``[expert_offset, expert_offset + n_experts)`` and computes
+their part of the result for the tokens routed to them.  What experts held
+elsewhere would add is left out (their chips add it in a deployment);
+shared experts run on every token.  With ``router_experts`` 0 every expert
+is held and the layer is the whole MoE FFN.
+
+Routing is per token: softmax scores (Qwen / Mixtral) or sigmoid scores
+with an optional selection-only bias (AFMoE), then top-k, and the chosen
+scores renormalized and scaled by ``route_scale``.  No capacity: every
+(token, held expert) pair is computed, so the layer matches a reference
+exactly and a token's result never depends on the other rows of the batch.
+Tokens outside ``token_mask`` (padding of a bucketed prefill, free decode
+slots) are routed nowhere.
+
+The pairs are sorted by expert and run as one grouped FFN over the held
+experts: the Pallas ``moe_gmm`` kernel on TPU, which fetches only the
+experts some pair was routed to, and ``jax.lax.ragged_dot`` elsewhere
+(differentiable, for training).  Each call returns its counts: ``pairs``
+(token-expert pairs computed here) and ``experts_hit`` (held experts with
+at least one pair).
 """
 from __future__ import annotations
 
-import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.context import constrain
 from repro.models.layers import dense_init
+
+# Grouped-FFN backend: "auto" is the Pallas kernel on TPU and ragged_dot
+# elsewhere; chosen at trace time, like the decode-attention backend.
+GMM_IMPL = ["auto"]      # "auto" | "pallas" | "xla"
 
 
 def init_moe_mlp(key, cfg, dtype) -> Dict:
     D, E, F = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
     ks = jax.random.split(key, 8)
     p = {
-        "router": dense_init(ks[0], (D, E), dtype, scale=0.02),
+        "router": dense_init(ks[0], (D, cfg.n_router_experts), dtype,
+                             scale=0.02),
         "w_gate": dense_init(ks[1], (E, D, F), dtype),
         "w_up": dense_init(ks[2], (E, D, F), dtype),
         "w_down": dense_init(ks[3], (E, F, D), dtype),
     }
+    if cfg.router_bias:
+        p["expert_bias"] = jnp.zeros((cfg.n_router_experts,), dtype)
     if cfg.n_shared_experts:
         S = cfg.n_shared_experts
         p["shared"] = {
             "w_gate": dense_init(ks[4], (S, D, F), dtype),
             "w_up": dense_init(ks[5], (S, D, F), dtype),
             "w_down": dense_init(ks[6], (S, F, D), dtype),
-            "gate": dense_init(ks[7], (D, 1), dtype, scale=0.02),
         }
+        if cfg.shared_expert_gate:
+            p["shared"]["gate"] = dense_init(ks[7], (D, 1), dtype, scale=0.02)
     return p
 
 
-def moe_capacity(n_tokens: int, cfg) -> int:
-    cap = int(math.ceil(cfg.capacity_factor * n_tokens * cfg.top_k
-                        / cfg.n_experts))
-    return max(8, ((cap + 7) // 8) * 8)  # pad to VPU-friendly multiple
+def route(cfg, p, xt) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """xt (T, D) -> (experts (T, K) int32 over the router's width, their
+    weights (T, K) f32, the scores as probabilities (T, E) f32)."""
+    logits = jnp.dot(xt, p["router"], preferred_element_type=jnp.float32)
+    if cfg.router_score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        probs = s / jnp.sum(s, axis=-1, keepdims=True)
+    else:
+        s = probs = jax.nn.softmax(logits, axis=-1)
+    pick = s + p["expert_bias"].astype(jnp.float32) if "expert_bias" in p \
+        else s
+    _, sel = jax.lax.top_k(pick, cfg.top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.route_scale
+    return sel.astype(jnp.int32), w, probs
 
 
-def _token_groups(T: int) -> int:
-    """Token groups = dp x seq shards (from the active sharding policy), so
-    routing, capacity and dispatch stay device-local at scale.  1 (global
-    routing) when undistributed."""
-    from repro.distributed.context import get_policy
-    pol = get_policy()
-    if pol is None:
-        return 1
-    g = pol.token_groups
-    return g if (g > 1 and T % g == 0) else 1
+def _use_pallas() -> bool:
+    impl = GMM_IMPL[0]
+    if impl == "auto":
+        return jax.default_backend() == "tpu"
+    return impl == "pallas"
 
 
-def moe_mlp(cfg, p, x, act, *, dropless: bool = False,
-            capacity_factor: float = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, D) -> (y: (B, S, D), aux_loss: scalar).
+def grouped_ffn(rows, p, group_sizes, act):
+    """Rows sorted by held expert through their expert's gated FFN; rows
+    at or past ``sum(group_sizes)`` are unspecified."""
+    if _use_pallas():
+        from repro.kernels import ops
+        return ops.moe_gmm(rows, p["w_gate"], p["w_up"], p["w_down"],
+                           group_sizes, act=act)
+    # as the kernel computes: f32 accumulation, the hidden rounded to the
+    # weights' dtype for the down projection
+    f32 = jnp.float32
+    g = jax.lax.ragged_dot(rows, p["w_gate"], group_sizes,
+                           preferred_element_type=f32)
+    u = jax.lax.ragged_dot(rows, p["w_up"], group_sizes,
+                           preferred_element_type=f32)
+    h = (act(g) * u).astype(p["w_down"].dtype)
+    return jax.lax.ragged_dot(h, p["w_down"], group_sizes,
+                              preferred_element_type=f32).astype(rows.dtype)
 
-    Routing: softmax -> top-k -> renormalize (Qwen/Mixtral convention).
-    Tokens are routed within per-device groups (GShard per-group capacity);
-    tokens over an expert's local capacity are dropped (their routed
-    contribution is zero; shared experts and the residual still serve them).
 
-    ``dropless=True`` (decode path): every expert runs on every token and
-    the top-k mask selects — exact, and nearly free at decode because the
-    step is bound by reading the expert weights regardless.
-    """
+def moe_mlp(cfg, p, x, act, token_mask: Optional[jnp.ndarray] = None
+            ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """x: (B, S, D) -> (y (B, S, D), aux_loss, counts).
+
+    ``token_mask`` (B, S) bool: tokens routed at all (None = every token).
+    ``counts``: ``pairs`` and ``experts_hit`` of this call, int32."""
     B, S, D = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    T = B * S
-    if dropless:
-        return _moe_dropless(cfg, p, x, act)
-    G = _token_groups(T)
-    Tg = T // G
-    import dataclasses as _dc
-    cfg_cap = cfg if capacity_factor is None else         _dc.replace(cfg, capacity_factor=capacity_factor)
-    C = moe_capacity(Tg, cfg_cap)
-    xt = constrain(x.reshape(T, D), "tok")
-    xg = xt.reshape(G, Tg, D)                                # dim0 sharded
-
-    logits = (xg @ p["router"]).astype(jnp.float32)          # (G, Tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)                   # (G, Tg, K)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-
-    # --- per-group capacity assignment: position of each (token, choice)
-    # within its expert's local queue, in token order ----------------------
-    onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)     # (G, Tg, K, E)
-    flat = onehot.reshape(G, Tg * K, E)
-    pos = jnp.cumsum(flat, axis=1) - flat                    # (G, Tg*K, E)
-    pos = jnp.sum(pos * flat, axis=-1).reshape(G, Tg, K)
-    keep = (pos < C)
-    pos = jnp.where(keep, pos, 0).astype(jnp.int32)
-
-    # dispatch: (G, Tg, K, E, C) -> reduce K -> (G, Tg, E, C)
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32)       # (G, Tg, K, C)
-    disp = jnp.einsum("gtke,gtkc->gtec",
-                      onehot * keep[..., None], pos_oh)
-    comb = jnp.einsum("gtke,gtkc->gtec",
-                      onehot * (top_p * keep)[..., None], pos_oh)
-
-    xin = jnp.einsum("gtec,gtd->gecd", disp.astype(x.dtype), xg)
-    h = act(jnp.einsum("gecd,edf->gecf", xin, p["w_gate"])) * \
-        jnp.einsum("gecd,edf->gecf", xin, p["w_up"])
-    eout = jnp.einsum("gecf,efd->gecd", h, p["w_down"])      # (G, E, C, D)
-    y = jnp.einsum("gtec,gecd->gtd", comb.astype(x.dtype), eout)
-    y = constrain(y.reshape(T, D), "tok")
-
-    # --- shared experts (always-on) ---------------------------------------
+    T, K, E = B * S, cfg.top_k, cfg.n_experts
+    xt = x.reshape(T, D)
+    sel, w, probs = route(cfg, p, xt)
+    local = sel - cfg.expert_offset
+    held = (local >= 0) & (local < E)
+    if token_mask is not None:
+        held = held & token_mask.reshape(T, 1)
+    # pairs sorted by held expert; the rest (group E) go to the end
+    gid = jnp.where(held, local, E).reshape(T * K)
+    order = jnp.argsort(gid, stable=True)
+    group_sizes = jnp.bincount(gid, length=E + 1)[:E].astype(jnp.int32)
+    out = grouped_ffn(jnp.take(xt, order // K, axis=0), p, group_sizes, act)
+    out = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, K, D)
+    y = jnp.sum(jnp.where(held[..., None], w[..., None] * out, 0.0), axis=1)
     if "shared" in p:
         sp = p["shared"]
         hs = act(jnp.einsum("td,sdf->tsf", xt, sp["w_gate"])) * \
-             jnp.einsum("td,sdf->tsf", xt, sp["w_up"])
+            jnp.einsum("td,sdf->tsf", xt, sp["w_up"])
         ys = jnp.einsum("tsf,sfd->td", hs, sp["w_down"])
-        sg = jax.nn.sigmoid((xt @ sp["gate"]).astype(jnp.float32))
-        y = y + ys * sg.astype(y.dtype)
-
-    # --- load-balance aux loss (Switch-style) ------------------------------
-    frac_tokens = jnp.mean(onehot.sum(2), axis=(0, 1))       # (E,)
-    frac_probs = jnp.mean(probs, axis=(0, 1))                # (E,)
-    aux = E * jnp.sum(frac_tokens * frac_probs) / K
-    return y.reshape(B, S, D), aux.astype(jnp.float32)
-
-
-def _moe_dropless(cfg, p, x, act) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Exact top-k MoE: all experts on all tokens, masked combine."""
-    B, S, D = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    T = B * S
-    xt = x.reshape(T, D)
-    logits = (xt @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    w = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32)
-                * top_p[..., None], axis=1)               # (T, E)
-    h = act(jnp.einsum("td,edf->tef", xt, p["w_gate"])) *         jnp.einsum("td,edf->tef", xt, p["w_up"])
-    eout = jnp.einsum("tef,efd->ted", h, p["w_down"])     # (T, E, D)
-    y = jnp.einsum("te,ted->td", w.astype(x.dtype), eout)
-    if "shared" in p:
-        sp = p["shared"]
-        hs = act(jnp.einsum("td,sdf->tsf", xt, sp["w_gate"])) *              jnp.einsum("td,sdf->tsf", xt, sp["w_up"])
-        ys = jnp.einsum("tsf,sfd->td", hs, sp["w_down"])
-        sg = jax.nn.sigmoid((xt @ sp["gate"]).astype(jnp.float32))
-        y = y + ys * sg.astype(y.dtype)
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(1), axis=0)
-    aux = E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)) / K
-    return y.reshape(B, S, D), aux.astype(jnp.float32)
+        if "gate" in sp:
+            ys = ys * jax.nn.sigmoid(
+                (xt @ sp["gate"]).astype(jnp.float32)).astype(ys.dtype)
+        y = y + ys
+    # load-balance aux loss (Switch-style) over the router's experts
+    En = cfg.n_router_experts
+    frac_tokens = jnp.mean(jax.nn.one_hot(sel, En, dtype=jnp.float32
+                                          ).sum(1), axis=0)
+    aux = En * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)) / K
+    counts = {"pairs": jnp.sum(group_sizes),
+              "experts_hit": jnp.sum(group_sizes > 0).astype(jnp.int32)}
+    return y.astype(x.dtype).reshape(B, S, D), aux, counts

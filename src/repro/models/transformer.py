@@ -17,6 +17,7 @@ See ``docs/ARCHITECTURE.md`` § "Models and kernels".
 from __future__ import annotations
 
 import functools
+import zlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -58,6 +59,11 @@ def _init_attn_layer(key, cfg: ArchConfig, dtype) -> Params:
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((hd,), dtype)
         p["k_norm"] = jnp.ones((hd,), dtype)
+    if cfg.attn_out_gate:
+        p["wg"] = dense_init(ks[5], (D, Hq * hd), dtype)
+    if cfg.sandwich_norm:       # norms of the attention and MLP outputs
+        p["post"] = {"ln1": _norm_init(cfg, D, dtype),
+                     "ln2": _norm_init(cfg, D, dtype)}
     return p
 
 
@@ -128,7 +134,8 @@ def init_params(cfg: ArchConfig, key, dtype=None) -> Params:
     params: Params = {"embed": embed_init(k_embed, (V, D), dtype)}
     blocks: Params = {}
     for kind, n in kind_counts(cfg).items():
-        keys = jax.random.split(jax.random.fold_in(k_blocks, hash(kind) % 2**31), n)
+        keys = jax.random.split(jax.random.fold_in(
+            k_blocks, zlib.crc32(kind.encode()) % 2**31), n)
         blocks[kind] = jax.vmap(
             lambda kk: _LAYER_INIT[kind](kk, cfg, dtype))(keys)
     params["blocks"] = blocks
@@ -209,24 +216,59 @@ def _rope(cfg, x, positions):
     return apply_rope(x, positions, cfg.rope_theta)
 
 
-def attn_layer_fwd(cfg, p, x, positions, *, kv_write: Optional[int] = None):
-    """Full-sequence attention layer. Returns (x, (k, v)) — roped k/v for the
-    cache when prefilling (kv_write = capacity) else (None, None)."""
-    h = _apply_norm(cfg, p["ln1"], x)
-    q, k, v = _project_qkv(cfg, p, h)
-    q = constrain(_rope(cfg, q, positions), "heads")
-    k = constrain(_rope(cfg, k, positions), "heads")
-    v = constrain(v, "heads")
-    o = attn_lib.attention(q, k, v, causal=cfg.causal, window=cfg.attn_window)
-    o = o.reshape(*x.shape[:2], -1) @ p["wo"]
-    x = constrain(x + o, "act")
+def _rope_if(cfg, x, positions, use):
+    """RoPE, or none on a layer whose (scanned) flag ``use`` is off."""
+    r = _rope(cfg, x, positions)
+    return r if use is None else jnp.where(use, r, x)
+
+
+def _attn_out(cfg, p, o, h, x):
+    """The attention branch's output added to the residual ``x``: the
+    output gate (on the normed input ``h``), wo, and the post norm."""
+    o = o.reshape(*x.shape[:2], -1)
+    if cfg.attn_out_gate:
+        o = o * jax.nn.sigmoid((h @ p["wg"]).astype(jnp.float32)
+                               ).astype(o.dtype)
+    o = o @ p["wo"]
+    if cfg.sandwich_norm:
+        o = _apply_norm(cfg, p["post"]["ln1"], o)
+    return x + o
+
+
+def _ffn_branch(cfg, p, x, token_mask=None):
+    """(x + the MLP or MoE branch, aux loss, MoE counts or {})."""
     h2 = _apply_norm(cfg, p["ln2"], x)
     if "router" in p["mlp"]:
-        y, aux = moe.moe_mlp(cfg, p["mlp"], h2, _ACTS[cfg.act])
+        y, aux, counts = moe.moe_mlp(cfg, p["mlp"], h2, _ACTS[cfg.act],
+                                     token_mask)
     else:
-        y = _apply_mlp(cfg, p["mlp"], h2)
-        aux = jnp.zeros((), jnp.float32)
-    x = constrain(x + y, "act")
+        y, aux, counts = _apply_mlp(cfg, p["mlp"], h2), \
+            jnp.zeros((), jnp.float32), {}
+    if cfg.sandwich_norm:
+        y = _apply_norm(cfg, p["post"]["ln2"], y)
+    return x + y, aux, counts
+
+
+def attn_layer_fwd(cfg, p, x, positions, *, kv_write: Optional[int] = None,
+                   window=None, rope=None, token_mask=None):
+    """Full-sequence attention layer.  Returns (x, (k, v), stats): roped
+    k/v for the cache when prefilling (kv_write = capacity) else None, and
+    ``stats`` the aux loss (``aux``) and, for a MoE layer, its counts.
+
+    ``window`` / ``rope`` (traced per-layer values in a scan of mixed
+    layers) override ``cfg.attn_window`` and RoPE-everywhere; tokens
+    outside ``token_mask`` (B, S) are routed to no expert."""
+    h = _apply_norm(cfg, p["ln1"], x)
+    q, k, v = _project_qkv(cfg, p, h)
+    q = constrain(_rope_if(cfg, q, positions, rope), "heads")
+    k = constrain(_rope_if(cfg, k, positions, rope), "heads")
+    v = constrain(v, "heads")
+    o = attn_lib.attention(q, k, v, causal=cfg.causal,
+                           window=cfg.attn_window if window is None
+                           else window)
+    x = constrain(_attn_out(cfg, p, o, h, x), "act")
+    x, aux, counts = _ffn_branch(cfg, p, x, token_mask)
+    x = constrain(x, "act")
     kv = None
     if kv_write is not None:
         S = k.shape[1]
@@ -242,13 +284,15 @@ def attn_layer_fwd(cfg, p, x, positions, *, kv_write: Optional[int] = None):
             kc = jnp.roll(k[:, S - kv_write:], shift, axis=1)
             vc = jnp.roll(v[:, S - kv_write:], shift, axis=1)
         kv = (kc, vc)
-    return x, kv, aux
+    return x, kv, dict(counts, aux=aux)
 
 
 def attn_layer_step(cfg, p, x, position, k_cache, v_cache, cache_len, *,
-                    zero_copy: bool = False):
+                    zero_copy: bool = False, window=None, rope=None,
+                    token_mask=None):
     """Single-token step. x: (B, 1, D); caches (B, C, kv, hd);
     cache_len: (B,) per-slot valid lengths (continuous batching).
+    Returns (x, k, v, MoE counts or {}).
 
     ``zero_copy=False`` (legacy copy path): the current token's K/V are
     written into the cache here and the updated cache-sized arrays are
@@ -263,12 +307,17 @@ def attn_layer_step(cfg, p, x, position, k_cache, v_cache, cache_len, *,
     the same path: eviction becomes a per-slot mask on the read (the slot
     the new row will land in holds the evicted, out-of-window entry), and
     the post-scan scatter at ``pos % C`` performs the overwrite.
+
+    ``window`` (a layer's sliding window over a full-length cache, traced
+    in a scan; 0 = full) gives each slot its first row in the window,
+    ``cache_len - window + 1``, so older rows are neither attended nor
+    fetched; ``rope`` and ``token_mask`` (B,) as in ``attn_layer_fwd``.
     """
     h = _apply_norm(cfg, p["ln1"], x)
     q, k, v = _project_qkv(cfg, p, h)
     pos2d = position if position.ndim >= 2 else position[:, None]
-    q = _rope(cfg, q, pos2d if not cfg.mrope else position)
-    k = _rope(cfg, k, pos2d if not cfg.mrope else position)
+    q = _rope_if(cfg, q, pos2d if not cfg.mrope else position, rope)
+    k = _rope_if(cfg, k, pos2d if not cfg.mrope else position, rope)
     B, C = k_cache.shape[:2]
     if zero_copy:
         valid_old = jnp.minimum(cache_len, C)
@@ -281,8 +330,13 @@ def attn_layer_step(cfg, p, x, position, k_cache, v_cache, cache_len, *,
             j = jnp.arange(C)[None, :]
             p_len = cache_len[:, None]
             slot_mask = (j < p_len) & ((p_len < C) | (j != jnp.mod(p_len, C)))
+        lo = None
+        if window is not None:
+            lo = jnp.where(window > 0,
+                           jnp.maximum(cache_len - window + 1, 0), 0)
         o = attn_lib.decode_attention_merged(q, k_cache, v_cache, valid_old,
-                                             k, v, kv_slot_mask=slot_mask)
+                                             k, v, kv_slot_mask=slot_mask,
+                                             kv_lo=lo)
         kv_out = (k[:, 0], v[:, 0])
     else:
         slot = jnp.mod(cache_len, C)      # == cache_len when C >= max_len
@@ -292,14 +346,10 @@ def attn_layer_step(cfg, p, x, position, k_cache, v_cache, cache_len, *,
         valid = jnp.minimum(cache_len + 1, C)
         o = attn_lib.decode_attention(q, k_cache, v_cache, valid)
         kv_out = (k_cache, v_cache)
-    o = o.reshape(x.shape[0], 1, -1) @ p["wo"]
-    x = x + o
-    h2 = _apply_norm(cfg, p["ln2"], x)
-    if "router" in p["mlp"]:
-        y, _ = moe.moe_mlp(cfg, p["mlp"], h2, _ACTS[cfg.act], dropless=True)
-    else:
-        y = _apply_mlp(cfg, p["mlp"], h2)
-    return x + y, kv_out[0], kv_out[1]
+    x = _attn_out(cfg, p, o, h, x)
+    x, _, counts = _ffn_branch(
+        cfg, p, x, None if token_mask is None else token_mask[:, None])
+    return x, kv_out[0], kv_out[1], counts
 
 
 def rec_layer_fwd(cfg, p, x, *, conv_state=None, h0=None, want_state=False):
@@ -326,12 +376,19 @@ def rec_layer_step(cfg, p, x, conv_state, h):
 # Full-model forward
 # ---------------------------------------------------------------------------
 
+def _embed(cfg, params, tokens):
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    return x
+
+
 def embed_tokens(cfg, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (x (B,S,D), positions)."""
     if "embeds" in batch:
         x = batch["embeds"]
     else:
-        x = jnp.take(params["embed"], batch["tokens"], axis=0)
+        x = _embed(cfg, params, batch["tokens"])
     if "positions" in batch:
         positions = batch["positions"]
     else:
@@ -351,10 +408,29 @@ def unembed(cfg, params, x) -> jnp.ndarray:
                                 preferred_element_type=jnp.float32), "logits")
 
 
+def _per_layer(cfg, g0: int, count: int):
+    """Scanned per-layer (window, rope) of layers g0 .. g0+count-1, or None
+    where the stack has one window and RoPE everywhere."""
+    if not (cfg.layer_windows or cfg.layer_rope):
+        return None
+    idx = range(g0, g0 + count)
+    return (jnp.asarray([cfg.layer_window(i) for i in idx], jnp.int32),
+            jnp.asarray([cfg.layer_uses_rope(i) for i in idx]))
+
+
+def _add_moe_counts(total: Dict, counts: Dict) -> None:
+    """Add a run's per-layer MoE counts, summed over its layers, to
+    ``total`` (as ``moe_pairs``, ``moe_experts_hit``)."""
+    for k, v in counts.items():
+        if k != "aux":
+            total[f"moe_{k}"] = total.get(f"moe_{k}", 0) + jnp.sum(v)
+
+
 def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
             mode: str = "train", max_len: Optional[int] = None,
             remat: bool = False, unroll: int = 1,
-            last_index=None) -> Tuple[jnp.ndarray, Any]:
+            last_index=None, token_mask=None,
+            return_stats: bool = False) -> Tuple[jnp.ndarray, Any]:
     """Full-sequence forward.
 
     mode="train":   returns (logits (B,S,V) f32, aux_loss scalar)
@@ -364,9 +440,14 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
     prompt token for right-padded (bucketed) prompts.  Logits are gathered
     there and ``cache["pos"]`` is set to ``last_index + 1`` so decode
     attention masks the pad K/V.  Only valid for models whose per-token
-    state is causal and batch-row-independent (pure attention with a
-    full-length cache); SSM/recurrent running states would integrate the
-    pad tokens — callers gate on that (see serving.engine).
+    state is causal and batch-row-independent (attention and dropless MoE
+    with a full-length cache); SSM/recurrent running states would
+    integrate the pad tokens — callers gate on that (see serving.engine).
+
+    ``token_mask`` (B, S) bool: tokens MoE layers route at all (padding is
+    routed nowhere).  ``return_stats`` adds a third output, the MoE layers'
+    counts summed over layers (``moe_pairs``, ``moe_experts_hit``; empty
+    without MoE layers).
     """
     assert mode in ("train", "prefill")
     x, positions = embed_tokens(cfg, params, batch)
@@ -377,14 +458,18 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
     cap = attn_cache_capacity(cfg, max_len) if want_cache else None
 
     aux_total = jnp.zeros((), jnp.float32)
+    stats: Dict[str, jnp.ndarray] = {}
     kv_stack = {"k": [], "v": []}
     ssm_states: Dict[str, list] = {"conv": [], "state": []}
     rec_states: Dict[str, list] = {"conv": [], "h": []}
 
-    def attn_body(x, p_l):
-        x, kv, aux = attn_layer_fwd(cfg, p_l, x, positions,
-                                    kv_write=cap if want_cache else None)
-        outs = (kv if kv is not None else (), aux)
+    def attn_body(x, xs):
+        p_l, win, rope = xs if len(xs) == 3 else (xs[0], None, None)
+        x, kv, st = attn_layer_fwd(cfg, p_l, x, positions,
+                                   kv_write=cap if want_cache else None,
+                                   window=win, rope=rope,
+                                   token_mask=token_mask)
+        outs = (kv if kv is not None else (), st)
         return x, outs
 
     def ssm_body(x, p_l):
@@ -399,26 +484,33 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
               "rec": rec_body}
 
     # Group maximal runs of the same kind and scan each run over its stacked
-    # params (hybrid patterns become several short scans over slices).
+    # params (hybrid patterns become several short scans over slices);
+    # per-layer windows and RoPE flags ride the scan beside the params.
     runs = _kind_runs(kinds)
     kind_cursor: Dict[str, int] = {}
+    g0 = 0
     for kind, count in runs:
         start = kind_cursor.get(kind, 0)
         kind_cursor[kind] = start + count
         stacked = jax.tree.map(lambda a: a[start:start + count],
                                params["blocks"][kind])
+        per = _per_layer(cfg, g0, count) if kind in ("attn", "moe") \
+            else None
+        g0 += count
         body = bodies[kind]
         if remat:
             body = jax.checkpoint(body)
-        x, outs = jax.lax.scan(body, x, stacked, unroll=unroll)
+        xs = (stacked,) + (per or ()) if kind in ("attn", "moe") else stacked
+        x, outs = jax.lax.scan(body, x, xs, unroll=unroll)
         if kind in ("attn", "moe"):
             if want_cache:
-                kv, aux = outs
+                kv, st = outs
                 kv_stack["k"].append(kv[0])
                 kv_stack["v"].append(kv[1])
             else:
-                _, aux = outs
-            aux_total = aux_total + jnp.sum(aux)
+                _, st = outs
+            aux_total = aux_total + jnp.sum(st["aux"])
+            _add_moe_counts(stats, st)
         elif kind == "ssm" and want_cache:
             conv_s, state = outs
             ssm_states["conv"].append(conv_s)
@@ -450,6 +542,8 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
     if rec_states["conv"]:
         cache["rec"] = {"conv": jnp.concatenate(rec_states["conv"], axis=0),
                         "h": jnp.concatenate(rec_states["h"], axis=0)}
+    if return_stats:
+        return logits, cache, stats
     return logits, cache
 
 
@@ -464,12 +558,14 @@ def _kind_runs(kinds):
 
 
 def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
-                cache: Cache, *, unroll: int = 1) -> Tuple[jnp.ndarray, Cache]:
+                cache: Cache, *, unroll: int = 1, token_mask=None,
+                return_stats: bool = False) -> Tuple[jnp.ndarray, Cache]:
     """One autoregressive step.
 
     batch: {"tokens": (B,) int32} or {"embeds": (B, 1, D)}
            (+ "positions": (B, 1) or (B, 1, 3) for mrope).
-    Returns (logits (B, V) f32, new cache).
+    Returns (logits (B, V) f32, new cache); ``token_mask`` and
+    ``return_stats`` as in ``forward`` (the mask is (B,)).
     """
     assert cfg.has_decode, f"{cfg.name} is encoder-only"
     pos = cache["pos"]  # (B,) per-slot positions
@@ -478,7 +574,7 @@ def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
         B = x.shape[0]
     else:
         toks = batch["tokens"].reshape(-1)
-        x = jnp.take(params["embed"], toks[:, None], axis=0)
+        x = _embed(cfg, params, toks[:, None])
         B = toks.shape[0]
     if pos.ndim == 0:
         pos = jnp.full((B,), pos, jnp.int32)
@@ -491,12 +587,23 @@ def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
     runs = _kind_runs(kinds)
     kind_cursor: Dict[str, int] = {}
     new_cache: Cache = {"pos": pos + 1}
+    stats: Dict[str, jnp.ndarray] = {}
     # collect per-kind outputs across runs, then reassemble stacks
-    collected: Dict[str, list] = {k: [] for k in ("attn_k", "attn_v",
-                                                  "ssm_conv", "ssm_state",
+    collected: Dict[str, list] = {k: [] for k in ("ssm_conv", "ssm_state",
                                                   "rec_conv", "rec_h")}
     # attn/moe share the "attn" cache stack; track separate cursor
     attnlike_cursor = 0
+    # A single attention run scans its cache slices and one scatter after
+    # the scan writes every layer's new row.  Several runs (dense layers
+    # before MoE ones) carry the whole stacked cache through their scans
+    # and write each layer's row in place as they go: a slice of the
+    # cache fed to a scan, or the cache read by several scans and written
+    # after them, would be copied whole.
+    kv_carry = sum(1 for k, _ in runs if k in ("attn", "moe")) > 1
+    if kv_carry:
+        kc_all, vc_all = cache["attn"]["k"], cache["attn"]["v"]
+        slot_all = jnp.mod(pos, kc_all.shape[2])
+    g0 = 0
 
     for kind, count in runs:
         start = kind_cursor.get(kind, 0)
@@ -506,32 +613,55 @@ def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
         if kind in ("attn", "moe"):
             a0 = attnlike_cursor
             attnlike_cursor += count
-            kc = cache["attn"]["k"][a0:a0 + count]
-            vc = cache["attn"]["v"][a0:a0 + count]
+            per = _per_layer(cfg, g0, count)
             # Zero-copy hot path: the scan only READS the cache and emits
-            # each layer's new (B, kv, hd) row; one scatter after the scan
-            # writes all rows — with a donated cache that's an in-place
-            # O(L*B)-row update instead of an O(cache-size) rewrite per
-            # layer.  Ring-buffer (windowed) caches use the same path:
-            # the merged partial masks out the slot being evicted
-            # (attn_layer_step builds the per-slot mask) and the post-scan
-            # scatter at pos % C is the eviction write itself.
+            # each layer's new (B, kv, hd) row; the rows land in the
+            # donated cache in place (an O(L*B)-row update instead of an
+            # O(cache-size) rewrite per layer).  Ring-buffer (windowed)
+            # caches use the same path: the merged partial masks out the
+            # slot being evicted (attn_layer_step builds the per-slot
+            # mask) and the row write at pos % C is the eviction write.
+            if kv_carry:
+                xs = (stacked, jnp.arange(a0, a0 + count))
+            else:
+                xs = (stacked, cache["attn"]["k"][a0:a0 + count],
+                      cache["attn"]["v"][a0:a0 + count])
+            if per is not None:
+                xs += per                       # (window, rope) a layer
 
-            def body(x, per):
-                p_l, k_l, v_l = per
-                x, k_l, v_l = attn_layer_step(cfg, p_l, x, positions, k_l,
-                                              v_l, pos, zero_copy=True)
-                return x, (k_l, v_l)
+            def body(carry, xs):
+                win = rope = None
+                if per is not None:
+                    xs, (win, rope) = xs[:-2], xs[-2:]
+                if kv_carry:
+                    (p_l, li), (x, kc, vc) = xs, carry
+                    k_l, v_l = kc[li], vc[li]
+                else:
+                    (p_l, k_l, v_l), x = xs, carry
+                x, k_l, v_l, counts = attn_layer_step(
+                    cfg, p_l, x, positions, k_l, v_l, pos, zero_copy=True,
+                    window=win, rope=rope, token_mask=token_mask)
+                if not kv_carry:
+                    return x, (k_l, v_l, counts)
+                bidx = jnp.arange(B)
+                kc = kc.at[li, bidx, slot_all].set(k_l)
+                vc = vc.at[li, bidx, slot_all].set(v_l)
+                return (x, kc, vc), counts
 
-            x, (kn, vn) = jax.lax.scan(body, x, (stacked, kc, vc),
-                                       unroll=unroll)
-            C = kc.shape[2]
-            slot = jnp.mod(pos, C)
-            bidx = jnp.arange(B)
-            kc = kc.at[:, bidx, slot].set(kn)        # (count, B, kv, hd) rows
-            vc = vc.at[:, bidx, slot].set(vn)
-            collected["attn_k"].append(kc)
-            collected["attn_v"].append(vc)
+            if kv_carry:
+                (x, kc_all, vc_all), counts = jax.lax.scan(
+                    body, (x, kc_all, vc_all), xs, unroll=unroll)
+                new_cache["attn"] = {"k": kc_all, "v": vc_all}
+            else:
+                x, (kn, vn, counts) = jax.lax.scan(body, x, xs,
+                                                   unroll=unroll)
+                kc, vc = cache["attn"]["k"], cache["attn"]["v"]
+                slot = jnp.mod(pos, kc.shape[2])
+                bidx = jnp.arange(B)
+                # (L, B, kv, hd) rows of every attention layer, one scatter
+                new_cache["attn"] = {"k": kc.at[:, bidx, slot].set(kn),
+                                     "v": vc.at[:, bidx, slot].set(vn)}
+            _add_moe_counts(stats, counts)
         elif kind == "ssm":
             cv = cache["ssm"]["conv"][start:start + count]
             st = cache["ssm"]["state"][start:start + count]
@@ -557,17 +687,17 @@ def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
             x, (cv, hh) = jax.lax.scan(body, x, (stacked, cv, hh), unroll=unroll)
             collected["rec_conv"].append(cv)
             collected["rec_h"].append(hh)
+        g0 += count
 
     x = _apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params, x)[:, 0, :]
 
-    if collected["attn_k"]:
-        new_cache["attn"] = {"k": jnp.concatenate(collected["attn_k"], 0),
-                             "v": jnp.concatenate(collected["attn_v"], 0)}
     if collected["ssm_conv"]:
         new_cache["ssm"] = {"conv": jnp.concatenate(collected["ssm_conv"], 0),
                             "state": jnp.concatenate(collected["ssm_state"], 0)}
     if collected["rec_conv"]:
         new_cache["rec"] = {"conv": jnp.concatenate(collected["rec_conv"], 0),
                             "h": jnp.concatenate(collected["rec_h"], 0)}
+    if return_stats:
+        return logits, new_cache, stats
     return logits, new_cache

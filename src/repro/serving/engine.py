@@ -119,11 +119,12 @@ class ContinuousBatcher:
         self.active: Dict[int, ServeRequest] = {}     # slot -> request
         self.free: List[int] = list(range(n_slots))
         # Bucketed (padded) prefill is exact only when every layer is
-        # batch-row-independent AND per-token causal (pure attention with a
-        # full-length cache): SSM/recurrent states integrate pad tokens, MoE
-        # capacity couples rows, and a ring buffer would evict real K/V.
+        # batch-row-independent AND per-token causal (attention with a
+        # full-length cache, and dropless MoE, which routes per token and
+        # routes pad tokens nowhere): SSM/recurrent states integrate pad
+        # tokens, and a ring buffer would evict real K/V.
         self._can_bucket = (
-            set(cfg.layer_kinds()) <= {"attn"}
+            set(cfg.layer_kinds()) <= {"attn", "moe"}
             and transformer.attn_cache_capacity(cfg, max_len) == max_len)
         # device-resident step I/O (rebuilt only when slot membership
         # changes; in steady state nothing crosses the host boundary except
@@ -176,17 +177,26 @@ class ContinuousBatcher:
     def _build_jits(self) -> None:
         cfg, n_slots, max_len = self.cfg, self.n_slots, self.max_len
 
+        # With MoE layers both fused steps also return the layers' counts
+        # (``stats``), and route free slots and pad tokens to no expert.
+        moe = "moe" in cfg.layer_kinds()
+
         def fused_decode(p, toks, active_mask, cache):
             old_pos = cache["pos"]
-            logits, cache = transformer.decode_step(cfg, p, {"tokens": toks},
-                                                    cache)
+            if moe:
+                logits, cache, stats = transformer.decode_step(
+                    cfg, p, {"tokens": toks}, cache, token_mask=active_mask,
+                    return_stats=True)
+            else:
+                logits, cache = transformer.decode_step(
+                    cfg, p, {"tokens": toks}, cache)
             # freeze free slots: their position must not advance (a wrapped
             # ring-buffer pos would corrupt a later admission) and their
             # garbage logits must not reach EOS bookkeeping
             cache["pos"] = jnp.where(active_mask, cache["pos"], old_pos)
             nxt = self._sampler(logits).astype(jnp.int32)
             nxt = jnp.where(active_mask, nxt, toks)
-            return nxt, cache
+            return (nxt, cache, stats) if moe else (nxt, cache)
 
         self._decode_fused = jax.jit(fused_decode, donate_argnums=(3,))
 
@@ -222,13 +232,18 @@ class ContinuousBatcher:
             is a per-slot select over the stacked leaves, not a Python
             ``.at[].set`` loop with one dispatch per leaf.
             """
-            logits, c1 = transformer.forward(
+            kw = {}
+            if moe:
+                kw = dict(return_stats=True, token_mask=(
+                    jnp.arange(toks.shape[1])[None, :] <= last_idx[:, None])
+                    & valid[:, None])
+            logits, c1, *stats = transformer.forward(
                 cfg, p, {"tokens": toks}, mode="prefill", max_len=max_len,
-                last_index=last_idx)
+                last_index=last_idx, **kw)
             rows = {k: c1[k] for k in ("attn", "ssm", "rec") if k in c1}
             cache = write_rows(cache, rows, slots, valid, last_idx + 1)
             first = self._sampler(logits).astype(jnp.int32)
-            return first, cache
+            return (first, cache, *stats)
 
         self._prefill_fused = jax.jit(fused_prefill, donate_argnums=(5,))
 
@@ -429,7 +444,7 @@ class ContinuousBatcher:
             act[:w, slot] = True
         outs = []
         for i in range(W):
-            nxt, self.cache = self._decode_fused(
+            nxt, self.cache, *_ = self._decode_fused(
                 self.params, jnp.asarray(toks[i]), jnp.asarray(act[i]),
                 self.cache)
             outs.append(nxt)
@@ -480,6 +495,7 @@ class ContinuousBatcher:
             assigned.append((i, slot, req))
         self.n_prefill_padded_tokens += P * bucket
         backend = self._choose_prefill_backend(P, bucket)
+        stats: Dict[str, Any] = {}        # the MoE layers' counts
         with tracing.span("pb.prefill") as sp:
             if backend == "pipeline":
                 # TTFT-critical cold-start path: the prompt runs the
@@ -494,16 +510,22 @@ class ContinuousBatcher:
                 first = self._sampler(logits).astype(jnp.int32)
                 self.n_prefill_pipeline += len(reqs)
             else:
-                first, self.cache = self._prefill_fused(
+                first, self.cache, *moe = self._prefill_fused(
                     self.params, jnp.asarray(toks), jnp.asarray(last_idx),
                     jnp.asarray(slots), jnp.asarray(valid), self.cache)
+                stats = moe[0] if moe else {}
+                if sp:
+                    for v in stats.values():
+                        v.copy_to_host_async()
             with tracing.span("pb.prefill.wait"):
                 # pbcheck: disable=R2 (designed sync: admission reads first tokens to catch immediate EOS before slot commit)
                 first_host = np.asarray(first)
             if sp:
                 sp.end(backend=backend, rows=P, bucket=bucket,
                        real_tokens=int(last_idx.sum()) + len(reqs),
-                       rids=[r.rid for r in reqs])
+                       rids=[r.rid for r in reqs],
+                       # pbcheck: disable=R2 (traced calls only: copied to the host beside the first tokens)
+                       **{k: int(v) for k, v in stats.items()})
         self.n_prefill_calls += 1
         self.n_prefill_reqs += len(reqs)
         for i, slot, req in assigned:
@@ -547,18 +569,27 @@ class ContinuousBatcher:
                 self._dev_tokens = jnp.asarray(toks)
                 self._dev_active = jnp.asarray(act)
                 self._io_dirty = False
-            nxt, self.cache = self._decode_fused(
+            nxt, self.cache, *moe = self._decode_fused(
                 self.params, self._dev_tokens, self._dev_active, self.cache)
+            stats = moe[0] if moe else {}
             self._dev_tokens = nxt
             kv_meta = bool(sp) and "attn" in self.cache
             if kv_meta:
-                # the slots' lengths come to the host with the tokens
+                # the slots' lengths and the step's MoE counts come to the
+                # host with the tokens
                 self.cache["pos"].copy_to_host_async()
+                for v in stats.values():
+                    v.copy_to_host_async()
             with tracing.span("pb.decode.wait"):
                 # pbcheck: disable=R2 (designed sync: THE one host transfer per decode step; EOS checks need the token ids)
                 nxt_host = np.asarray(nxt)
             if kv_meta:
                 sp.meta.update(self._kv_block_meta())
+                if stats:
+                    # pbcheck: disable=R2 (traced steps only: copied to the host beside the step's tokens)
+                    sp.meta.update({k: int(v) for k, v in stats.items()})
+                    sp.meta["moe_experts_held"] = self.cfg.n_experts * sum(
+                        k == "moe" for k in self.cfg.layer_kinds())
             self.n_decode_steps += 1
             finished = []
             done_slots: List[Tuple[int, ServeRequest]] = []
@@ -588,8 +619,11 @@ class ContinuousBatcher:
         """The decode kernel's K/V blocks per layer in the step just run,
         by its own rule: ``kv_blocks`` those it fetched (every slot up to
         its valid length, free slots at the length their last request
-        left), ``kv_blocks_all`` those of the whole cache.  Called after
-        the step, before its finished requests leave ``active``."""
+        left), ``kv_blocks_all`` those of the whole cache.  With sliding
+        layers also, summed over them, ``kv_blocks_window`` (from each
+        slot's first row in the window) and ``kv_blocks_nowindow`` (what
+        the same layers would fetch without it).  Called after the step,
+        before its finished requests leave ``active``."""
         from repro.kernels import decode_attention as dec
         k = self.cache["attn"]["k"]                   # (L, slots, C, Hkv, d)
         C, Hkv, d = k.shape[2:]
@@ -597,8 +631,15 @@ class ContinuousBatcher:
         # pbcheck: disable=R2 (traced steps only: copied to the host beside the step's tokens, so nothing more is waited for)
         pos = np.array(self.cache["pos"])
         pos[list(self.active)] -= 1          # the kernel ran before the step
-        return {"kv_blocks": dec.kv_blocks(pos, C, bk),
-                "kv_blocks_all": self.n_slots * -(-C // bk)}
+        out = {"kv_blocks": dec.kv_blocks(pos, C, bk),
+               "kv_blocks_all": self.n_slots * -(-C // bk)}
+        wins = [self.cfg.layer_window(i) for i in range(self.cfg.n_layers)]
+        if any(wins):
+            out["kv_blocks_window"] = sum(dec.kv_blocks(
+                pos, C, bk, lo=np.maximum(pos - w + 1, 0)) for w in wins if w)
+            out["kv_blocks_nowindow"] = out["kv_blocks"] * sum(
+                1 for w in wins if w)
+        return out
 
     def _deposit_prefixes(self, pairs: Sequence[Tuple[int, ServeRequest]]
                           ) -> None:

@@ -336,3 +336,67 @@ def test_lora_merge_unmerge_roundtrip():
     merged = ops.lora_merge(W, A, B, 0.5)
     back = ops.lora_merge(merged, A, B, -0.5)
     np.testing.assert_allclose(np.asarray(back), np.asarray(W), atol=1e-5)
+
+
+@pytest.mark.parametrize("B,C,Hq,Hkv,d,window", [
+    (4, 512, 8, 1, 128, 200),        # G 8 (Trinity's ratio), window in a block
+    (5, 320, 4, 2, 64, 130),         # ragged tail, window past a block edge
+    (3, 256, 8, 8, 64, 1000),        # window longer than every slot
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_lower_bound(B, C, Hq, Hkv, d, window, dtype):
+    """A per-slot lower bound (a sliding window over a full-length cache)
+    equals the oracle with rows under it masked; rows under the bound and
+    at or past ``lens`` hold NaN, and blocks wholly under it are counted
+    as not fetched."""
+    q = rnd((B, 1, Hq, d), dtype, 40)
+    k = rnd((B, C, Hkv, d), dtype, 41)
+    v = rnd((B, C, Hkv, d), dtype, 42)
+    lens = np.random.default_rng(3).integers(1, C + 1, size=B)
+    lens[0] = 0
+    lens[-1] = C
+    lo = np.maximum(lens - window + 1, 0)
+    under = np.arange(C)[None, :] < lo[:, None]
+
+    def poison(x):
+        x = poison_past(x, lens)
+        return jnp.where(jnp.asarray(under)[:, :, None, None],
+                         jnp.asarray(np.nan, x.dtype), x)
+    o = ops.decode_attention(q, poison(k), poison(v), jnp.asarray(lens),
+                             lo=jnp.asarray(lo, jnp.int32), block_k=128)
+    assert np.isfinite(np.asarray(o, np.float32)).all()
+    r = ref.decode_attention_ref(q[:, 0], jnp.moveaxis(k, 1, 2),
+                                 jnp.moveaxis(v, 1, 2), jnp.asarray(lens),
+                                 slot_mask=~under)
+    np.testing.assert_allclose(np.asarray(o[:, 0], np.float32),
+                               np.asarray(r, np.float32),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    nblk = -(-np.minimum(lens, C) // 128)
+    assert dec.kv_blocks(lens, C, 128, lo=lo) == int(
+        np.maximum(nblk - lo // 128, 1).sum())
+    assert dec.kv_blocks(lens, C, 128, lo=lo) <= dec.kv_blocks(lens, C, 128)
+
+
+@pytest.mark.parametrize("sizes,tm,tf", [
+    ((5, 0, 40, 3), 16, 32),         # an empty expert between two others
+    ((0, 0, 7, 0), 8, 64),           # one expert hit, rows well past it
+    ((0, 0, 0, 0), 16, 32),          # no pair held here at all
+    ((16, 16, 16, 16), 16, 16),      # groups on tile edges, every F tile
+])
+def test_moe_gmm_matches_einsum(sizes, tm, tf):
+    """The grouped expert FFN in interpret mode against a plain einsum per
+    expert; rows past the groups are left to the caller."""
+    G, D, F, M = 4, 32, 64, 70
+    x = rnd((M, D), jnp.float32, 50)
+    wg = rnd((G, D, F), jnp.float32, 51) / 6
+    wu = rnd((G, D, F), jnp.float32, 52) / 6
+    wd = rnd((G, F, D), jnp.float32, 53) / 8
+    gs = np.asarray(sizes, np.int32)
+    out = np.asarray(ops.moe_gmm(x, wg, wu, wd, jnp.asarray(gs), tm=tm,
+                                 tf=tf))
+    ends = np.cumsum(gs)
+    for g in range(G):
+        r = slice(ends[g] - gs[g], ends[g])
+        want = (jax.nn.silu(x[r] @ wg[g]) * (x[r] @ wu[g])) @ wd[g]
+        np.testing.assert_allclose(out[r], np.asarray(want), atol=2e-5,
+                                   rtol=2e-5)

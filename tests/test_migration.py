@@ -186,10 +186,10 @@ def test_ring_zero_copy_step_matches_write_path():
     # per-slot positions: unwrapped, exactly-at-capacity, wrapped
     for pos_vals in ([3, 8, 13], [1, 7, 20]):
         pos = jnp.asarray(pos_vals, jnp.int32)
-        x0, k0, v0 = attn_layer_step(cfg, p_l, x, pos[:, None], kc, vc, pos,
-                                     zero_copy=False)
-        x1, k1, v1 = attn_layer_step(cfg, p_l, x, pos[:, None], kc, vc, pos,
-                                     zero_copy=True)
+        x0, k0, v0, _ = attn_layer_step(cfg, p_l, x, pos[:, None], kc, vc,
+                                        pos, zero_copy=False)
+        x1, k1, v1, _ = attn_layer_step(cfg, p_l, x, pos[:, None], kc, vc,
+                                        pos, zero_copy=True)
         np.testing.assert_allclose(np.asarray(x0), np.asarray(x1),
                                    atol=2e-5, rtol=2e-5)
         # write path returns the full cache; zero-copy returns the row the

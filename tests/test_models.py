@@ -136,6 +136,6 @@ def test_param_counts_match_published():
 def test_cells_matrix():
     from repro.configs.base import cells
     cs = cells(include_skipped=True)
-    assert len(cs) == 40
+    assert len(cs) == 44
     runnable = [c for c in cs if c[2]]
-    assert len(runnable) == 31
+    assert len(runnable) == 34

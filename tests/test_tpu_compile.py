@@ -4,8 +4,9 @@ Interpret mode on the CPU does not check Mosaic's tiling rules, so every
 kernel test can pass while the chip refuses the kernel.  These tests hand
 the serving path's kernels and the full-width decode step to the TPU
 compiler at qwen3-1.7b widths, and the decode kernel at qwen2.5-14b's
-too (one pipeline stage's slots and cache); a refusal fails here, at no
-chip time.
+too (one pipeline stage's slots and cache), and Trinity-Mini's windowed
+decode kernel and grouped expert FFN; a refusal fails here, at no chip
+time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers that
@@ -96,6 +97,38 @@ def test_decode_kernel_compiles_for_v5e(one_chip, form, C, widths):
     # reaches it unpadded (a ragged C is a partial last block)
     assert re.search(r"%decode_attention\.\d+ = .*custom-call\(", hlo)
     assert "pad(" not in hlo
+
+
+def test_windowed_decode_kernel_compiles_for_v5e(one_chip):
+    """Trinity-Mini's decode: G = 8, a 4096-row cache, each slot's lower
+    bound prefetched beside its length."""
+    B, C, Hq, Hkv, d = 8, 4096, 32, 4, 128
+    bf16 = jnp.bfloat16
+    hlo = _compiled_text(
+        lambda q, k, v, n, kn, vn, lo: ops.decode_attention(
+            q, k, v, n, k_new=kn, v_new=vn, lo=lo, interpret=False),
+        _struct((B, 1, Hq, d), bf16, one_chip),
+        _struct((B, C, Hkv, d), bf16, one_chip),
+        _struct((B, C, Hkv, d), bf16, one_chip),
+        _struct((B,), jnp.int32, one_chip),
+        _struct((B, 1, Hkv, d), bf16, one_chip),
+        _struct((B, 1, Hkv, d), bf16, one_chip),
+        _struct((B,), jnp.int32, one_chip))
+    assert re.search(r"%decode_attention\.\d+ = .*custom-call\(", hlo)
+
+
+@pytest.mark.parametrize("M", [64, 32768])    # a decode step's, a prefill's
+def test_moe_gmm_compiles_for_v5e(one_chip, M):
+    """The grouped expert FFN at Trinity-Mini's widths (16 held experts of
+    1024 over d 2048), under the name the trace reduction finds."""
+    G, D, F = 16, 2048, 1024
+    bf16 = jnp.bfloat16
+    hlo = _compiled_text(
+        lambda x, g, u, w, n: ops.moe_gmm(x, g, u, w, n, interpret=False),
+        _struct((M, D), bf16, one_chip), _struct((G, D, F), bf16, one_chip),
+        _struct((G, D, F), bf16, one_chip), _struct((G, F, D), bf16, one_chip),
+        _struct((G,), jnp.int32, one_chip))
+    assert re.search(r"%moe_gmm\.\d+ = .*custom-call\(", hlo)
 
 
 def test_lora_merge_compiles_for_v5e(one_chip):
