@@ -116,7 +116,9 @@ def build_pipeline_prefill(cfg: ArchConfig, *, n_stages: int, n_micro: int,
         B = (tokens if tokens is not None else embeds).shape[0]
         mb = B // n_micro
         D = cfg.d_model
-        blocks = params["blocks"][kind]          # (L_local, ...) local slice
+        # (L_local, ...) local slice; routed experts whole, read in place
+        blocks, experts = transformer.scan_params(
+            params["blocks"][kind], 0, L_local)
 
         positions = jnp.broadcast_to(jnp.arange(seq_len)[None, :],
                                      (mb, seq_len))
@@ -134,7 +136,7 @@ def build_pipeline_prefill(cfg: ArchConfig, *, n_stages: int, n_micro: int,
                     x, st = mamba2.ssm_block_fwd(cfg, p_l, x)
                     return x, (st if return_cache else None)
                 x, kv, _ = transformer.attn_layer_fwd(
-                    cfg, p_l, x, positions,
+                    cfg, transformer.with_experts(p_l, experts), x, positions,
                     kv_write=cap if return_cache else None)
                 return x, (kv if return_cache else None)
             x, st = jax.lax.scan(layer, x, blocks)
@@ -277,8 +279,9 @@ def build_pipeline_prefill_seqchunk(cfg: ArchConfig, *, n_stages: int,
         embeds = batch.get("embeds")
         B = (tokens if tokens is not None else embeds).shape[0]
         D = cfg.d_model
-        blocks = params["blocks"][kind]
-        L_local = jax.tree.leaves(blocks)[0].shape[0]
+        L_local = jax.tree.leaves(params["blocks"][kind])[0].shape[0]
+        blocks, experts = transformer.scan_params(
+            params["blocks"][kind], 0, L_local)
 
         def embed_chunk(i):
             if tokens is not None:
@@ -323,7 +326,8 @@ def build_pipeline_prefill_seqchunk(cfg: ArchConfig, *, n_stages: int,
                         kv_valid_len=q_off + chunk,
                         block_k=max(chunk, 1024)), q.dtype)
                 o = o.reshape(B, chunk, -1) @ p_l["wo"]
-                x, _, _ = _ffn_branch(cfg, p_l, x + o)
+                x, _, _ = _ffn_branch(
+                    cfg, transformer.with_experts(p_l, experts), x + o)
                 return x, kv_l
 
             x_out, kv = jax.lax.scan(layer, x_in, (blocks, kv))

@@ -22,9 +22,17 @@ neither fetched nor computed, and rows at or past ``sum(group_sizes)``
 written — the caller masks them.  The number of visits is a traced
 scalar, so the grid is dynamic.
 
-Layouts: x (M, D); w_gate, w_up (G, D, F); w_down (G, F, D); group_sizes
-(G,) int32 -> (M, D) in ``x.dtype``.  ``tm`` rows per m tile (M is padded
-to a multiple), ``tf`` hidden columns per F tile (F a multiple of it).
+The weights are read in place from the stack of every MoE layer:
+``layer``, prefetched beside the group metadata, picks the layer's experts
+in the weights' index maps.  A slice of the stack handed to the kernel
+would be materialized (a custom call's operand is never fused), copying
+every held expert before each call; a caller that holds one layer's
+experts passes ``w[None]`` at layer 0.
+
+Layouts: x (M, D); w_gate, w_up (L, G, D, F); w_down (L, G, F, D);
+group_sizes (G,) int32; layer () or (1,) int32 -> (M, D) in ``x.dtype``.
+``tm`` rows per m tile (M is padded to a multiple), ``tf`` hidden columns
+per F tile (F a multiple of it).
 """
 from __future__ import annotations
 
@@ -76,8 +84,8 @@ def group_metadata(group_sizes: jnp.ndarray, m: int, tm: int):
     return (offsets, group_ids, m_tile_ids), group_tiles.sum()
 
 
-def _kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, x_ref, wg_ref,
-            wu_ref, wd_ref, out_ref, acc_ref, *, tm: int, n_f: int,
+def _kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, layer_ref, x_ref,
+            wg_ref, wu_ref, wd_ref, out_ref, acc_ref, *, tm: int, n_f: int,
             act: Callable):
     i = pl.program_id(0)
     f = pl.program_id(1)
@@ -105,13 +113,15 @@ def _kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, x_ref, wg_ref,
 
 
 def moe_gmm(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
-            w_down: jnp.ndarray, group_sizes: jnp.ndarray, *,
-            act: Callable = jax.nn.silu, tm: Optional[int] = None,
-            tf: Optional[int] = None, interpret: bool = True) -> jnp.ndarray:
-    """Rows of each group through its expert's gated FFN; see the module
-    docstring.  Rows at or past ``sum(group_sizes)`` are left unwritten."""
+            w_down: jnp.ndarray, group_sizes: jnp.ndarray,
+            layer: jnp.ndarray, *, act: Callable = jax.nn.silu,
+            tm: Optional[int] = None, tf: Optional[int] = None,
+            interpret: bool = True) -> jnp.ndarray:
+    """Rows of each group through its expert's gated FFN, the experts
+    those of ``layer`` in the stacked weights; see the module docstring.
+    Rows at or past ``sum(group_sizes)`` are left unwritten."""
     M, D = x.shape
-    G, _, F = w_gate.shape
+    _, G, _, F = w_gate.shape
     d_tm, d_tf = tiles_for(M, F)
     tm, tf = tm or d_tm, tf or d_tf
     assert F % tf == 0, (F, tf)
@@ -120,23 +130,24 @@ def moe_gmm(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
         x = jnp.pad(x, ((0, Mp - M), (0, 0)))
     n_f = F // tf
     meta, visits = group_metadata(group_sizes.astype(jnp.int32), Mp, tm)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def rows(i, f, offsets, group_ids, m_tile_ids):
+    def rows(i, f, offsets, group_ids, m_tile_ids, layer):
         return m_tile_ids[i], 0
 
-    def cols(i, f, offsets, group_ids, m_tile_ids):
-        return group_ids[i], 0, f
+    def cols(i, f, offsets, group_ids, m_tile_ids, layer):
+        return layer[0], group_ids[i], 0, f
 
-    def down(i, f, offsets, group_ids, m_tile_ids):
-        return group_ids[i], f, 0
+    def down(i, f, offsets, group_ids, m_tile_ids, layer):
+        return layer[0], group_ids[i], f, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(visits, n_f),
         in_specs=[pl.BlockSpec((tm, D), rows),
-                  pl.BlockSpec((None, D, tf), cols),
-                  pl.BlockSpec((None, D, tf), cols),
-                  pl.BlockSpec((None, tf, D), down)],
+                  pl.BlockSpec((None, None, D, tf), cols),
+                  pl.BlockSpec((None, None, D, tf), cols),
+                  pl.BlockSpec((None, None, tf, D), down)],
         out_specs=pl.BlockSpec((tm, D), rows),
         scratch_shapes=[pltpu.VMEM((tm, D), jnp.float32)],
     )
@@ -149,5 +160,5 @@ def moe_gmm(x: jnp.ndarray, w_gate: jnp.ndarray, w_up: jnp.ndarray,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name=NAME,
-    )(*meta, x, w_gate, w_up, w_down)
+    )(*meta, layer, x, w_gate, w_up, w_down)
     return out[:M]

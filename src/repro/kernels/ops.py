@@ -71,14 +71,16 @@ def decode_attention(q, k_cache, v_cache, lens, *, k_new=None, v_new=None,
 
 
 @functools.partial(jax.jit, static_argnames=("act", "tm", "tf", "interpret"))
-def moe_gmm(x, w_gate, w_up, w_down, group_sizes, *, act=jax.nn.silu,
-            tm: Optional[int] = None, tf: Optional[int] = None,
-            interpret: Optional[bool] = None):
-    """Grouped expert FFN over rows sorted by expert: x (M, D), w_gate /
-    w_up (G, D, F), w_down (G, F, D), group_sizes (G,) -> (M, D).  Rows at
-    or past sum(group_sizes) are unspecified."""
-    return _gmm.moe_gmm(x, w_gate, w_up, w_down, group_sizes, act=act,
-                        tm=tm, tf=tf, interpret=_interpret(interpret))
+def moe_gmm(x, w_gate, w_up, w_down, group_sizes, layer, *,
+            act=jax.nn.silu, tm: Optional[int] = None,
+            tf: Optional[int] = None, interpret: Optional[bool] = None):
+    """Grouped expert FFN over rows sorted by expert, the experts read in
+    place from stacked weights at ``layer``: x (M, D), w_gate / w_up
+    (L, G, D, F), w_down (L, G, F, D), group_sizes (G,), layer int32
+    -> (M, D).  Rows at or past sum(group_sizes) are unspecified."""
+    return _gmm.moe_gmm(x, w_gate, w_up, w_down, group_sizes, layer,
+                        act=act, tm=tm, tf=tf,
+                        interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

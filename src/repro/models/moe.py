@@ -23,6 +23,11 @@ experts some pair was routed to, and ``jax.lax.ragged_dot`` elsewhere
 (differentiable, for training).  Each call returns its counts: ``pairs``
 (token-expert pairs computed here) and ``experts_hit`` (held experts with
 at least one pair).
+
+In a layer scan the routed experts' weights are the whole stacks of the
+model's MoE layers, and the layer's params carry its index into them as
+``layer``: the kernel reads them there in place, where a scanned slice
+would be copied whole before each call.
 """
 from __future__ import annotations
 
@@ -36,6 +41,9 @@ from repro.models.layers import dense_init
 # Grouped-FFN backend: "auto" is the Pallas kernel on TPU and ragged_dot
 # elsewhere; chosen at trace time, like the decode-attention backend.
 GMM_IMPL = ["auto"]      # "auto" | "pallas" | "xla"
+
+# the routed experts' weights, (G, D, F) / (G, F, D) a layer
+EXPERTS = ("w_gate", "w_up", "w_down")
 
 
 def init_moe_mlp(key, cfg, dtype) -> Dict:
@@ -86,22 +94,21 @@ def _use_pallas() -> bool:
     return impl == "pallas"
 
 
-def grouped_ffn(rows, p, group_sizes, act):
-    """Rows sorted by held expert through their expert's gated FFN; rows
-    at or past ``sum(group_sizes)`` are unspecified."""
+def grouped_ffn(rows, experts, layer, group_sizes, act):
+    """Rows sorted by held expert through their expert's gated FFN, the
+    experts those of ``layer`` in the stacked ``experts`` (w_gate, w_up,
+    w_down); rows at or past ``sum(group_sizes)`` are unspecified."""
     if _use_pallas():
         from repro.kernels import ops
-        return ops.moe_gmm(rows, p["w_gate"], p["w_up"], p["w_down"],
-                           group_sizes, act=act)
+        return ops.moe_gmm(rows, *experts, group_sizes, layer, act=act)
     # as the kernel computes: f32 accumulation, the hidden rounded to the
     # weights' dtype for the down projection
     f32 = jnp.float32
-    g = jax.lax.ragged_dot(rows, p["w_gate"], group_sizes,
-                           preferred_element_type=f32)
-    u = jax.lax.ragged_dot(rows, p["w_up"], group_sizes,
-                           preferred_element_type=f32)
-    h = (act(g) * u).astype(p["w_down"].dtype)
-    return jax.lax.ragged_dot(h, p["w_down"], group_sizes,
+    wg, wu, wd = (w[layer] for w in experts)
+    g = jax.lax.ragged_dot(rows, wg, group_sizes, preferred_element_type=f32)
+    u = jax.lax.ragged_dot(rows, wu, group_sizes, preferred_element_type=f32)
+    h = (act(g) * u).astype(wd.dtype)
+    return jax.lax.ragged_dot(h, wd, group_sizes,
                               preferred_element_type=f32).astype(rows.dtype)
 
 
@@ -109,8 +116,10 @@ def moe_mlp(cfg, p, x, act, token_mask: Optional[jnp.ndarray] = None
             ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
     """x: (B, S, D) -> (y (B, S, D), aux_loss, counts).
 
-    ``token_mask`` (B, S) bool: tokens routed at all (None = every token).
-    ``counts``: ``pairs`` and ``experts_hit`` of this call, int32."""
+    ``p`` holds one layer's routed experts, or with ``layer`` (its index)
+    the whole stacks of every MoE layer.  ``token_mask`` (B, S) bool:
+    tokens routed at all (None = every token).  ``counts``: ``pairs`` and
+    ``experts_hit`` of this call, int32."""
     B, S, D = x.shape
     T, K, E = B * S, cfg.top_k, cfg.n_experts
     xt = x.reshape(T, D)
@@ -123,7 +132,12 @@ def moe_mlp(cfg, p, x, act, token_mask: Optional[jnp.ndarray] = None
     gid = jnp.where(held, local, E).reshape(T * K)
     order = jnp.argsort(gid, stable=True)
     group_sizes = jnp.bincount(gid, length=E + 1)[:E].astype(jnp.int32)
-    out = grouped_ffn(jnp.take(xt, order // K, axis=0), p, group_sizes, act)
+    if "layer" in p:
+        experts, layer = [p[k] for k in EXPERTS], p["layer"]
+    else:
+        experts, layer = [p[k][None] for k in EXPERTS], 0
+    out = grouped_ffn(jnp.take(xt, order // K, axis=0), experts, layer,
+                      group_sizes, act)
     out = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, K, D)
     y = jnp.sum(jnp.where(held[..., None], w[..., None] * out, 0.0), axis=1)
     if "shared" in p:

@@ -418,6 +418,35 @@ def _per_layer(cfg, g0: int, count: int):
             jnp.asarray([cfg.layer_uses_rope(i) for i in idx]))
 
 
+def scan_params(blocks: Params, start: int, count: int):
+    """(the params a layer scan over ``blocks[start:start + count]`` slices,
+    the routed experts' whole stacks or None).
+
+    A dot fuses the scan's slice of its weight, but a ``moe_gmm`` operand
+    is materialized: a sliced stack would copy all of a layer's held
+    experts before each call.  So the experts stay whole and each scanned
+    layer carries its index into them (``mlp/layer``); ``with_experts``
+    puts them back in the scan's body."""
+    def run(tree):
+        return jax.tree.map(lambda a: a[start:start + count], tree)
+    mlp = blocks.get("mlp", {})
+    if "router" not in mlp:
+        return run(blocks), None
+    stacked = run(dict(blocks, mlp={k: v for k, v in mlp.items()
+                                    if k not in moe.EXPERTS}))
+    stacked["mlp"]["layer"] = jnp.arange(start, start + count,
+                                         dtype=jnp.int32)
+    return stacked, {k: mlp[k] for k in moe.EXPERTS}
+
+
+def with_experts(p_l: Params, experts) -> Params:
+    """A scanned layer's params with the experts' stacks of
+    ``scan_params`` (None: a layer without routed experts)."""
+    if experts is None:
+        return p_l
+    return dict(p_l, mlp=dict(p_l["mlp"], **experts))
+
+
 def _add_moe_counts(total: Dict, counts: Dict) -> None:
     """Add a run's per-layer MoE counts, summed over its layers, to
     ``total`` (as ``moe_pairs``, ``moe_experts_hit``)."""
@@ -463,9 +492,10 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
     ssm_states: Dict[str, list] = {"conv": [], "state": []}
     rec_states: Dict[str, list] = {"conv": [], "h": []}
 
-    def attn_body(x, xs):
+    def attn_body(experts, x, xs):
         p_l, win, rope = xs if len(xs) == 3 else (xs[0], None, None)
-        x, kv, st = attn_layer_fwd(cfg, p_l, x, positions,
+        x, kv, st = attn_layer_fwd(cfg, with_experts(p_l, experts), x,
+                                   positions,
                                    kv_write=cap if want_cache else None,
                                    window=win, rope=rope,
                                    token_mask=token_mask)
@@ -492,12 +522,13 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict, *,
     for kind, count in runs:
         start = kind_cursor.get(kind, 0)
         kind_cursor[kind] = start + count
-        stacked = jax.tree.map(lambda a: a[start:start + count],
-                               params["blocks"][kind])
+        stacked, experts = scan_params(params["blocks"][kind], start, count)
         per = _per_layer(cfg, g0, count) if kind in ("attn", "moe") \
             else None
         g0 += count
         body = bodies[kind]
+        if kind in ("attn", "moe"):
+            body = functools.partial(body, experts)
         if remat:
             body = jax.checkpoint(body)
         xs = (stacked,) + (per or ()) if kind in ("attn", "moe") else stacked
@@ -608,8 +639,7 @@ def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
     for kind, count in runs:
         start = kind_cursor.get(kind, 0)
         kind_cursor[kind] = start + count
-        stacked = jax.tree.map(lambda a: a[start:start + count],
-                               params["blocks"][kind])
+        stacked, experts = scan_params(params["blocks"][kind], start, count)
         if kind in ("attn", "moe"):
             a0 = attnlike_cursor
             attnlike_cursor += count
@@ -639,8 +669,9 @@ def decode_step(cfg: ArchConfig, params: Params, batch: Dict,
                 else:
                     (p_l, k_l, v_l), x = xs, carry
                 x, k_l, v_l, counts = attn_layer_step(
-                    cfg, p_l, x, positions, k_l, v_l, pos, zero_copy=True,
-                    window=win, rope=rope, token_mask=token_mask)
+                    cfg, with_experts(p_l, experts), x, positions, k_l, v_l,
+                    pos, zero_copy=True, window=win, rope=rope,
+                    token_mask=token_mask)
                 if not kv_carry:
                     return x, (k_l, v_l, counts)
                 bidx = jnp.arange(B)

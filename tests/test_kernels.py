@@ -392,11 +392,38 @@ def test_moe_gmm_matches_einsum(sizes, tm, tf):
     wu = rnd((G, D, F), jnp.float32, 52) / 6
     wd = rnd((G, F, D), jnp.float32, 53) / 8
     gs = np.asarray(sizes, np.int32)
-    out = np.asarray(ops.moe_gmm(x, wg, wu, wd, jnp.asarray(gs), tm=tm,
-                                 tf=tf))
+    out = np.asarray(ops.moe_gmm(x, wg[None], wu[None], wd[None],
+                                 jnp.asarray(gs), 0, tm=tm, tf=tf))
     ends = np.cumsum(gs)
     for g in range(G):
         r = slice(ends[g] - gs[g], ends[g])
         want = (jax.nn.silu(x[r] @ wg[g]) * (x[r] @ wu[g])) @ wd[g]
         np.testing.assert_allclose(out[r], np.asarray(want), atol=2e-5,
                                    rtol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("M,sizes", [
+    (40, (5, 0, 20, 3)),             # a decode step's pairs, tiles of 128
+    (1100, (300, 0, 513, 90)),       # a prefill's, tiles of 256
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_reads_stacked_weights_in_place(layer, M, sizes, dtype):
+    """The experts of one layer of a (L, G, ...) stack, read in place at
+    ``layer``, give bit for bit what that layer's weights alone give at
+    layer 0; the other layers (NaN here) are never read.  Expert 1 is
+    empty and rows past the groups are left to the caller."""
+    L, G, D, F = 5, 4, 32, 1024      # F in two tiles of 512
+    x = rnd((M, D), dtype, 54)
+    ws = [rnd((G, D, F), dtype, 55) / 6, rnd((G, D, F), dtype, 56) / 6,
+          rnd((G, F, D), dtype, 57) / 8]
+    stacks = [jnp.full((L,) + w.shape, jnp.nan, dtype).at[layer].set(w)
+              for w in ws]
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = np.asarray(ops.moe_gmm(x, *stacks, gs, jnp.int32(layer)),
+                     np.float32)
+    alone = np.asarray(ops.moe_gmm(x, *(w[None] for w in ws), gs, 0),
+                       np.float32)
+    n = sum(sizes)
+    assert np.isfinite(got[:n]).all()
+    np.testing.assert_array_equal(got[:n], alone[:n])

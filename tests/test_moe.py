@@ -116,3 +116,52 @@ def test_trinity_serves_bucketed_and_batching_changes_no_token(trinity):
         assert 0 < m["moe_experts_hit"] <= m["moe_experts_held"]
         assert m["moe_pairs"] <= m["n_active"] * cfg.top_k * n_moe
         assert m["kv_blocks_window"] <= m["kv_blocks_nowindow"]
+
+
+def _sliced_scan_params(blocks, start, count):
+    """The layer scan's slice of every weight, the routed experts' too:
+    how the stack ran before the kernel read the experts in place."""
+    return jax.tree.map(lambda a: a[start:start + count], blocks), None
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("arch", ["trinity-mini", "qwen2-moe-a2.7b"])
+def test_experts_read_in_place_change_no_bit(arch, impl, monkeypatch):
+    """Prefill, decode steps and (on ragged_dot) the training gradient
+    through experts read from the whole stacks at each layer's index equal
+    bit for bit what the scan's per-layer slices of them gave.  Trinity's
+    decode scans carry the cache over two attention runs, qwen2-moe's one
+    run scans its cache slices; experts 2-4 of 8 are held."""
+    cfg = get_arch(arch).reduced(n_experts=3, router_experts=8,
+                                 expert_offset=2)
+    params = T.init_params(cfg, KEY)
+    rng = np.random.default_rng(5)
+    toks = jnp.asarray(rng.integers(0, 250, size=(3, 20)), jnp.int32)
+    last = jnp.asarray([19, 12, 4], jnp.int32)
+    mask = jnp.arange(20)[None, :] <= last[:, None]
+    live = jnp.asarray([True, False, True])
+    monkeypatch.setattr(moe, "GMM_IMPL", [impl])
+
+    def run():
+        lg, cache, st = jax.jit(lambda p: T.forward(
+            cfg, p, {"tokens": toks}, mode="prefill", max_len=32,
+            last_index=last, token_mask=mask, return_stats=True))(params)
+        out = [lg, st]
+        step = jax.jit(lambda p, t, c: T.decode_step(
+            cfg, p, {"tokens": t}, c, token_mask=live, return_stats=True))
+        for _ in range(8):              # past Trinity's window of 16
+            lg, cache, st = step(params, jnp.argmax(lg, -1), cache)
+            out += [lg, st]
+        if impl == "xla":
+            def loss(p):
+                logits, aux = T.forward(cfg, p, {"tokens": toks},
+                                        remat=True)
+                return jnp.mean(logits) + aux
+            out.append(jax.jit(jax.grad(loss))(params)["blocks"]["moe"])
+        return jax.tree.map(np.asarray, out)
+
+    got = run()
+    monkeypatch.setattr(T, "scan_params", _sliced_scan_params)
+    want = run()
+    assert int(got[1]["moe_pairs"]) > 0
+    jax.tree.map(np.testing.assert_array_equal, got, want)

@@ -5,13 +5,14 @@ kernel test can pass while the chip refuses the kernel.  These tests hand
 the serving path's kernels and the full-width decode step to the TPU
 compiler at qwen3-1.7b widths, and the decode kernel at qwen2.5-14b's
 too (one pipeline stage's slots and cache), and Trinity-Mini's windowed
-decode kernel and grouped expert FFN; a refusal fails here, at no chip
-time.
+decode kernel and grouped expert FFN, alone and in Trinity's decode step
+and prefill; a refusal fails here, at no chip time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers that
 collected different tests would run none.
 """
+import dataclasses
 import os
 import re
 
@@ -22,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_arch
 from repro.kernels import ops
+from repro.models import moe
 from repro.models import transformer as T
 from repro.models.attention import decode_attn_impl
 
@@ -120,15 +122,69 @@ def test_windowed_decode_kernel_compiles_for_v5e(one_chip):
 @pytest.mark.parametrize("M", [64, 32768])    # a decode step's, a prefill's
 def test_moe_gmm_compiles_for_v5e(one_chip, M):
     """The grouped expert FFN at Trinity-Mini's widths (16 held experts of
-    1024 over d 2048), under the name the trace reduction finds."""
-    G, D, F = 16, 2048, 1024
+    1024 over d 2048), reading one layer's experts in place from the
+    stacks of 30 MoE layers, under the name the trace reduction finds."""
+    L, G, D, F = 30, 16, 2048, 1024
     bf16 = jnp.bfloat16
     hlo = _compiled_text(
-        lambda x, g, u, w, n: ops.moe_gmm(x, g, u, w, n, interpret=False),
-        _struct((M, D), bf16, one_chip), _struct((G, D, F), bf16, one_chip),
-        _struct((G, D, F), bf16, one_chip), _struct((G, F, D), bf16, one_chip),
-        _struct((G,), jnp.int32, one_chip))
+        lambda x, g, u, w, n, l: ops.moe_gmm(x, g, u, w, n, l,
+                                             interpret=False),
+        _struct((M, D), bf16, one_chip),
+        _struct((L, G, D, F), bf16, one_chip),
+        _struct((L, G, D, F), bf16, one_chip),
+        _struct((L, G, F, D), bf16, one_chip),
+        _struct((G,), jnp.int32, one_chip), _struct((), jnp.int32, one_chip))
     assert re.search(r"%moe_gmm\.\d+ = .*custom-call\(", hlo)
+    assert not _ONE_LAYERS_EXPERTS.search(hlo)
+
+
+# a buffer holding one layer's held experts (w_gate / w_up, w_down)
+_ONE_LAYERS_EXPERTS = re.compile(r"bf16\[(1,)?16,(2048,1024|1024,2048)\]")
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_trinity_steps_read_the_stacked_experts_in_place(one_chip,
+                                                        monkeypatch, step):
+    """Trinity-Mini at one chip's 16 experts, 8 slots and a 4096-row
+    cache: the serving decode step and a bucketed prefill (512 tokens a
+    row) hand ``moe_gmm`` the whole (30, 16, ...) expert stacks, and no
+    buffer of one layer's experts is made for it."""
+    monkeypatch.setattr(ops, "_interpret", lambda override: False)
+    monkeypatch.setattr(moe, "GMM_IMPL", ["pallas"])
+    cfg = dataclasses.replace(get_arch("trinity-mini"), n_experts=16)
+    B, C, S = 8, 4096, 512
+
+    def placed(tree):
+        return jax.tree.map(lambda a: _struct(a.shape, a.dtype, one_chip),
+                            tree)
+
+    params = placed(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    if step == "decode":
+        cache = jax.eval_shape(lambda: T.init_cache(cfg, B, C))
+        cache["pos"] = jax.ShapeDtypeStruct((B,), jnp.int32)
+        hlo = _compiled_text(
+            lambda p, t, c, m: T.decode_step(
+                cfg, p, {"tokens": t}, c, token_mask=m, return_stats=True),
+            params, _struct((B,), jnp.int32, one_chip), placed(cache),
+            _struct((B,), jnp.bool_, one_chip))
+    else:
+        hlo = _compiled_text(
+            lambda p, t, li, m: T.forward(
+                cfg, p, {"tokens": t}, mode="prefill", max_len=C,
+                last_index=li, token_mask=m, return_stats=True),
+            params, _struct((B, S), jnp.int32, one_chip),
+            _struct((B,), jnp.int32, one_chip),
+            _struct((B, S), jnp.bool_, one_chip))
+    calls = [ln for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "moe_gmm/pallas_call" in ln]
+    assert calls
+    stacks = ["bf16[30,16,2048,1024]"] * 2 + ["bf16[30,16,1024,2048]"]
+    for ln in calls:
+        # the trace reduction finds the kernel by this name
+        assert re.search(r"%moe_gmm\.\d+ = .*custom-call\(", ln), ln
+        assert re.findall(r"bf16\[30,16,\d+,\d+\]", ln) == stacks, ln
+    assert not _ONE_LAYERS_EXPERTS.search(hlo)
 
 
 def test_lora_merge_compiles_for_v5e(one_chip):
